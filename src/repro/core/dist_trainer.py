@@ -164,12 +164,15 @@ class DistTrainer:
             donate: bool = True, prefetch: int = 0,
             max_chunk: int = 128, faults: Optional[FaultSchedule] = None,
             min_quorum: int = 1, checkpoint_dir: Optional[str] = None,
-            checkpoint_every: int = 0,
-            resume: bool = False) -> Tuple[DiLoCoState, Dict]:
+            checkpoint_every: int = 0, resume: bool = False,
+            consume: bool = False) -> Tuple[DiLoCoState, Dict]:
         """data_fn(step) -> per-worker-stacked batch pytree.
 
         ``chunked`` selects the scan-fused hot path (see module docstring);
-        ``donate`` donates state buffers to the chunk/outer jits;
+        ``donate`` donates state buffers to the chunk/outer jits (the
+        caller's state is copied once first, so it survives the run,
+        unless ``consume`` hands it over as is: no copy, half the peak
+        state memory, and the passed-in state is dead afterwards);
         ``prefetch`` > 0 assembles batches that many steps ahead on a
         background thread; ``max_chunk`` caps the scanned chunk length —
         ending a chunk early is always safe (between events ``after_step``
@@ -224,7 +227,7 @@ class DistTrainer:
                 inner_live = jax.jit(
                     eng.inner_chunk_live,
                     donate_argnums=(0,) if donate else ())
-        if donate:
+        if donate and not consume:
             # the first chunk donates the caller's state buffers; copy once
             # so the object the caller passed in survives the run
             state = jax.tree.map(jnp.copy, state)

@@ -1,12 +1,13 @@
 """Shared interpret-mode resolution for every Pallas kernel package,
 plus the fp8 per-tile QK^T contraction the attention kernels share.
 
-One override point for the whole kernel suite: ``interpret`` defaults to
-*backend-selected* — the Pallas interpreter is only used on CPU hosts
-(where Mosaic cannot compile); on TPU the kernels compile.
-``REPRO_PALLAS_INTERPRET=0|1`` force-overrides the selection, and
-``pallas_mode()`` reports the resolved mode so benchmarks can record
-which path actually ran.
+One selection point for the whole kernel suite: ``interpret`` defaults to
+*backend-selected* — the Pallas interpreter runs if and only if the
+backend is ``cpu`` (where Mosaic cannot compile); everywhere else the
+kernels compile.  There is no override: a kernel on the chip either
+compiles or fails, it never slows down to the interpreter.
+``pallas_mode()`` reports the resolved mode so reports can record which
+path actually ran.
 
 Every ``kernels/<name>/ops.py`` must resolve ``interpret`` through this
 module (enforced by the ``kernel-contract`` lint pass in
@@ -16,7 +17,6 @@ interpreter on TPU.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -56,10 +56,7 @@ def qk_dot_fp8(q, k, *, narrow_dot: bool):
 
 
 def default_interpret() -> bool:
-    """Interpret only where Mosaic can't compile (CPU), unless overridden."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """Interpret if and only if the backend is the CPU."""
     return jax.default_backend() == "cpu"
 
 

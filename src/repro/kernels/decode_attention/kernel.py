@@ -14,6 +14,12 @@ Differences from the prefill flash kernel:
   softmax state (m, l, acc) lives in VMEM scratch across the sweep.
 
 Validated in interpret mode against ``ref.reference_decode_attention``.
+
+The paged kernels (decode, verify, and their quantized-pool variants)
+share one body, ``_paged_kernel``: grid = (slots, logical blocks), each
+step DMAs a whole physical pool block (bs, KV, D) named by the block
+table and sweeps the KV heads inside the body.  Single-token decode is
+the T = 1 case of verify.
 """
 from __future__ import annotations
 
@@ -32,8 +38,8 @@ DEFAULT_BK = 256
 
 
 def _qk(q, k, *, fp8: bool, narrow_dot: bool):
-    """The QK^T contraction every kernel body below shares: f32 dot, or
-    the per-row fp8 tile path (``common.qk_dot_fp8``) behind ``fp8``."""
+    """The paged kernel's QK^T contraction: f32 dot, or the per-row fp8
+    tile path (``common.qk_dot_fp8``) behind ``fp8``."""
     if fp8:
         return qk_dot_fp8(q, k, narrow_dot=narrow_dot)
     return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -79,12 +85,24 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                        / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel(tab_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale: float, window: int,
-                         bs: int, n_b: int, fp8: bool = False,
-                         narrow_dot: bool = False):
+def _paged_kernel(tab_ref, start_ref, ntok_ref, q_ref, k_ref, v_ref, *refs,
+                  scale: float, window: int, bs: int, n_b: int, T: int,
+                  G: int, quantized: bool, fp8: bool, narrow_dot: bool):
+    """One (slot, logical block) grid step over ALL KV heads.  The K/V
+    tiles are whole pool blocks ``(bs, KV, D)`` — their last two dims are
+    the pool's own, which is what Mosaic's tiling rule needs when KV is
+    not a multiple of 8 — and the heads are swept inside the body.  Query
+    rows are (T, G) flattened to T*G per head: row r is token t = r // G
+    at absolute position ``start + t``; tokens beyond ``n_tok`` are
+    padding (fully masked).  Quantized pools carry (bs, KV) f32 scale
+    tiles on the same block-table index map and dequantize on load."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
+    else:
+        o_ref, m_scr, l_scr, acc_scr = refs
     s_idx = pl.program_id(0)
-    ib = pl.program_id(2)
+    ib = pl.program_id(1)
+    KV = k_ref.shape[2]
 
     @pl.when(ib == 0)
     def _init():
@@ -92,184 +110,95 @@ def _paged_decode_kernel(tab_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (bs, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (bs, D)
-    q_pos = qpos_ref[s_idx]                           # scalar int32
+    start = start_ref[s_idx]                          # scalar int32
+    n_tok = ntok_ref[s_idx]                           # scalar int32
     mapped = tab_ref[s_idx, ib] >= 0                  # −1 = unmapped block
-
-    s = _qk(q, k, fp8=fp8, narrow_dot=narrow_dot) * scale
+    row_t = jax.lax.broadcasted_iota(jnp.int32, (T * G, 1), 0) // G
+    q_pos = start + row_t                             # (T*G, 1)
+    valid = (start >= 0) & (row_t < n_tok)
     # blocks hold contiguous positions: logical position = ib*bs + lane
-    k_pos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    ok = (k_pos <= q_pos) & mapped
-    if window > 0:
-        ok &= (q_pos - k_pos) < window
-    s = jnp.where(ok[None, :], s, NEG_INF)            # (G, bs)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-
-    @pl.when(ib == n_b - 1)
-    def _fin():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_decode_dequant_kernel(tab_ref, qpos_ref, q_ref, k_ref, v_ref,
-                                 ks_ref, vs_ref, o_ref, m_scr, l_scr,
-                                 acc_scr, *, scale: float, window: int,
-                                 bs: int, n_b: int):
-    """Quantized-pool variant of ``_paged_decode_kernel``: the K/V tiles
-    arrive in the pool's narrow dtype (int8 / fp8) and are dequantized
-    on load with the per-token-per-head scale tiles riding the same
-    block-table index map — the wide cache never exists in VMEM either."""
-    s_idx = pl.program_id(0)
-    ib = pl.program_id(2)
-
-    @pl.when(ib == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
-    ks = ks_ref[0, :, 0]                              # (bs,) f32
-    vs = vs_ref[0, :, 0]
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks[:, None]   # (bs, D)
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs[:, None]
-    q_pos = qpos_ref[s_idx]                           # scalar int32
-    mapped = tab_ref[s_idx, ib] >= 0                  # −1 = unmapped block
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    k_pos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-    ok = (k_pos <= q_pos) & mapped
-    if window > 0:
-        ok &= (q_pos - k_pos) < window
-    s = jnp.where(ok[None, :], s, NEG_INF)            # (G, bs)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-
-    @pl.when(ib == n_b - 1)
-    def _fin():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_verify_dequant_kernel(tab_ref, start_ref, ntok_ref, q_ref, k_ref,
-                                 v_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
-                                 acc_scr, *, scale: float, window: int,
-                                 bs: int, n_b: int, T: int, G: int):
-    """Quantized-pool variant of ``_paged_verify_kernel`` (see
-    ``_paged_decode_dequant_kernel`` for the dequant-on-load contract)."""
-    s_idx = pl.program_id(0)
-    ib = pl.program_id(2)
-
-    @pl.when(ib == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, :, 0].astype(jnp.float32).reshape(T * G, -1)   # (T*G, D)
-    ks = ks_ref[0, :, 0]                              # (bs,) f32
-    vs = vs_ref[0, :, 0]
-    k = k_ref[0, :, 0].astype(jnp.float32) * ks[:, None]   # (bs, D)
-    v = v_ref[0, :, 0].astype(jnp.float32) * vs[:, None]
-    start = start_ref[s_idx]                          # scalar int32
-    n_tok = ntok_ref[s_idx]                           # scalar int32
-    mapped = tab_ref[s_idx, ib] >= 0                  # −1 = unmapped block
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    row_t = jax.lax.broadcasted_iota(jnp.int32, (T * G, 1), 0) // G
-    q_pos = start + row_t                             # (T*G, 1)
-    valid = (start >= 0) & (row_t < n_tok)
     k_pos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     ok = valid & mapped & (k_pos <= q_pos)
     if window > 0:
         ok &= (q_pos - k_pos) < window
-    s = jnp.where(ok, s, NEG_INF)                     # (T*G, bs)
+    if quantized:
+        head = jax.lax.broadcasted_iota(jnp.int32, (bs, KV), 1)
+        ks_all = ks_ref[0].astype(jnp.float32)        # (bs, KV)
+        vs_all = vs_ref[0].astype(jnp.float32)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-
-    @pl.when(ib == n_b - 1)
-    def _fin():
-        o_ref[0, :, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                          ).reshape(T, G, -1).astype(o_ref.dtype)
-
-
-def _paged_verify_kernel(tab_ref, start_ref, ntok_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                         window: int, bs: int, n_b: int, T: int, G: int,
-                         fp8: bool = False, narrow_dot: bool = False):
-    """Multi-query-per-slot variant: the q tile holds T query tokens per
-    slot (speculative verification / multi-token prefill), occupying
-    contiguous positions ``start .. start + n - 1``.  Rows are (T, G)
-    flattened to (T*G, D) so the MXU contraction stays a single dot; the
-    causal predicate is evaluated per row group against the row's own
-    position ``start + t``."""
-    s_idx = pl.program_id(0)
-    ib = pl.program_id(2)
-
-    @pl.when(ib == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, :, 0].astype(jnp.float32).reshape(T * G, -1)   # (T*G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)            # (bs, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (bs, D)
-    start = start_ref[s_idx]                          # scalar int32
-    n_tok = ntok_ref[s_idx]                           # scalar int32
-    mapped = tab_ref[s_idx, ib] >= 0                  # −1 = unmapped block
-
-    s = _qk(q, k, fp8=fp8, narrow_dot=narrow_dot) * scale
-    # row r of the flattened tile is query token t = r // G at absolute
-    # position start + t; tokens beyond n_tok are padding (fully masked)
-    row_t = jax.lax.broadcasted_iota(jnp.int32, (T * G, 1), 0) // G
-    q_pos = start + row_t                             # (T*G, 1)
-    valid = (start >= 0) & (row_t < n_tok)
-    k_pos = ib * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    ok = valid & mapped & (k_pos <= q_pos)
-    if window > 0:
-        ok &= (q_pos - k_pos) < window
-    s = jnp.where(ok, s, NEG_INF)                     # (T*G, bs)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+    for h in range(KV):
+        q = q_ref[0, h].astype(jnp.float32)           # (T*G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)     # (bs, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        if quantized:   # select head h's scale column: (bs, 1)
+            k = k * jnp.sum(jnp.where(head == h, ks_all, 0.0), axis=1,
+                            keepdims=True)
+            v = v * jnp.sum(jnp.where(head == h, vs_all, 0.0), axis=1,
+                            keepdims=True)
+        s = _qk(q, k, fp8=fp8, narrow_dot=narrow_dot) * scale
+        s = jnp.where(ok, s, NEG_INF)                 # (T*G, bs)
+        m_prev = m_scr[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[h] = m_new
 
     @pl.when(ib == n_b - 1)
     def _fin():
-        o_ref[0, :, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-                          ).reshape(T, G, -1).astype(o_ref.dtype)
+        for h in range(KV):
+            o_ref[0, h] = (acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)
+                           ).astype(o_ref.dtype)
+
+
+def _paged_call(q, k_pool, v_pool, scales, block_tables, start_pos, n_tokens,
+                *, window: int, interpret: bool, fp8: bool):
+    """Shared launcher: grid (S, MB), the block table (and per-slot start /
+    count) as scalar-prefetch operands so each step DMAs the physical block
+    the table names.  q (S, T, KV, G, D) is laid out head-major as
+    (S, KV, T*G, D) for the kernel and back again on the way out."""
+    S, T, KV, G, D = q.shape
+    NB, bs = k_pool.shape[:2]
+    MB = block_tables.shape[1]
+    qh = q.transpose(0, 2, 1, 3, 4).reshape(S, KV, T * G, D)
+    kernel = functools.partial(
+        _paged_kernel, scale=1.0 / math.sqrt(D), window=window, bs=bs,
+        n_b=MB, T=T, G=G, quantized=bool(scales), fp8=fp8,
+        narrow_dot=fp8 and not interpret)
+
+    def slot_map(s, ib, tab, st, nt):
+        return (s, 0, 0, 0)
+
+    def block_map(s, ib, tab, st, nt):
+        return (jnp.maximum(tab[s, ib], 0), 0, 0, 0)
+
+    def scale_map(s, ib, tab, st, nt):
+        return (jnp.maximum(tab[s, ib], 0), 0, 0)
+
+    in_specs = [pl.BlockSpec((1, KV, T * G, D), slot_map),
+                pl.BlockSpec((1, bs, KV, D), block_map),
+                pl.BlockSpec((1, bs, KV, D), block_map)]
+    in_specs += [pl.BlockSpec((1, bs, KV), scale_map)] * len(scales)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, MB),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, KV, T * G, D), slot_map),
+        scratch_shapes=[
+            pltpu.VMEM((KV, T * G, 1), jnp.float32),
+            pltpu.VMEM((KV, T * G, 1), jnp.float32),
+            pltpu.VMEM((KV, T * G, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, KV, T * G, D), q.dtype),
+        interpret=interpret,
+    )(block_tables, start_pos, n_tokens, qh, k_pool, v_pool, *scales)
+    return out.reshape(S, KV, T, G, D).transpose(0, 2, 1, 3, 4)
 
 
 def paged_verify_attention_fwd(q, k_pool, v_pool, block_tables, start_pos,
@@ -285,41 +214,9 @@ def paged_verify_attention_fwd(q, k_pool, v_pool, block_tables, start_pos,
     slot); n_tokens: (S,) int32 live query tokens per slot.  The fresh K/V
     for all T tokens must already be scattered into the pool — causality
     among them is purely positional, exactly like the single-query kernel.
-    Returns (S, T, KV, G, D)."""
-    S, T, KV, G, D = q.shape
-    NB, bs = k_pool.shape[:2]
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    kernel = functools.partial(_paged_verify_kernel, scale=scale,
-                               window=window, bs=bs, n_b=MB, T=T, G=G,
-                               fp8=fp8, narrow_dot=fp8 and not interpret)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, KV, MB),
-        in_specs=[
-            pl.BlockSpec((1, T, 1, G, D),
-                         lambda s, h, ib, tab, st, nt: (s, 0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, st, nt:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, st, nt:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, T, 1, G, D),
-                               lambda s, h, ib, tab, st, nt: (s, 0, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, T, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables, start_pos, n_tokens, q, k_pool, v_pool)
+    ``fp8`` runs QK^T on per-row fp8 tiles.  Returns (S, T, KV, G, D)."""
+    return _paged_call(q, k_pool, v_pool, (), block_tables, start_pos,
+                       n_tokens, window=window, interpret=interpret, fp8=fp8)
 
 
 def paged_verify_attention_dequant_fwd(q, k_pool, v_pool, k_scale, v_scale,
@@ -331,46 +228,13 @@ def paged_verify_attention_dequant_fwd(q, k_pool, v_pool, k_scale, v_scale,
     ``v_scale`` the (NB, bs, KV) f32 per-token-per-head amax scales;
     tiles are dequantized on load inside the kernel.  Shapes otherwise
     as ``paged_verify_attention_fwd``."""
-    S, T, KV, G, D = q.shape
-    NB, bs = k_pool.shape[:2]
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    kernel = functools.partial(_paged_verify_dequant_kernel, scale=scale,
-                               window=window, bs=bs, n_b=MB, T=T, G=G)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, KV, MB),
-        in_specs=[
-            pl.BlockSpec((1, T, 1, G, D),
-                         lambda s, h, ib, tab, st, nt: (s, 0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, st, nt:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, st, nt:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda s, h, ib, tab, st, nt:
-                         (jnp.maximum(tab[s, ib], 0), 0, h)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda s, h, ib, tab, st, nt:
-                         (jnp.maximum(tab[s, ib], 0), 0, h)),
-        ],
-        out_specs=pl.BlockSpec((1, T, 1, G, D),
-                               lambda s, h, ib, tab, st, nt: (s, 0, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, T, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables, start_pos, n_tokens, q, k_pool, v_pool,
-      k_scale, v_scale)
+    return _paged_call(q, k_pool, v_pool, (k_scale, v_scale), block_tables,
+                       start_pos, n_tokens, window=window,
+                       interpret=interpret, fp8=False)
+
+
+def _one_token(q_pos):
+    return jnp.where(q_pos >= 0, 1, 0).astype(jnp.int32)
 
 
 def paged_decode_attention_dequant_fwd(q, k_pool, v_pool, k_scale, v_scale,
@@ -380,97 +244,29 @@ def paged_decode_attention_dequant_fwd(q, k_pool, v_pool, k_scale, v_scale,
     """Quantized-pool single-token paged decode attention (see
     ``paged_verify_attention_dequant_fwd`` for the scale contract).
     Shapes otherwise as ``paged_decode_attention_fwd``."""
-    S, KV, G, D = q.shape
-    NB, bs = k_pool.shape[:2]
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    kernel = functools.partial(_paged_decode_dequant_kernel, scale=scale,
-                               window=window, bs=bs, n_b=MB)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, KV, MB),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda s, h, ib, tab, qp: (s, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, qp:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, qp:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda s, h, ib, tab, qp:
-                         (jnp.maximum(tab[s, ib], 0), 0, h)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda s, h, ib, tab, qp:
-                         (jnp.maximum(tab[s, ib], 0), 0, h)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda s, h, ib, tab, qp: (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables, q_pos, q, k_pool, v_pool, k_scale, v_scale)
+    return paged_verify_attention_dequant_fwd(
+        q[:, None], k_pool, v_pool, k_scale, v_scale, block_tables, q_pos,
+        _one_token(q_pos), window=window, interpret=interpret)[:, 0]
 
 
 def paged_decode_attention_fwd(q, k_pool, v_pool, block_tables, q_pos, *,
                                window: int = 0, interpret: bool = True,
                                fp8: bool = False):
-    """Block-table-indexed decode attention over a shared paged KV pool.
+    """Block-table-indexed decode attention over a shared paged KV pool —
+    the T = 1 case of ``paged_verify_attention_fwd``.
 
     q: (S, KV, G, D) one token per active slot; k_pool/v_pool: (NB, bs, KV, D)
     fixed-size physical blocks; block_tables: (S, MB) int32 — logical block j
     of slot s lives in physical block ``block_tables[s, j]`` (−1 = unmapped);
     q_pos: (S,) int32 absolute query positions (−1 = inactive slot).
 
-    The block table is a scalar-prefetch operand, so the per-(slot, block)
-    pool tile is DMA'd straight from the physical block the table names — the
-    gather never materializes a per-slot contiguous cache.  Validity is
+    The gather never materializes a per-slot contiguous cache.  Validity is
     positional (blocks hold contiguous positions), so stale pool contents
     beyond ``q_pos`` and unmapped table slots are masked, never read into the
     softmax.  Returns (S, KV, G, D)."""
-    S, KV, G, D = q.shape
-    NB, bs = k_pool.shape[:2]
-    MB = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
-    kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               window=window, bs=bs, n_b=MB,
-                               fp8=fp8, narrow_dot=fp8 and not interpret)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, KV, MB),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda s, h, ib, tab, qp: (s, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, qp:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda s, h, ib, tab, qp:
-                         (jnp.maximum(tab[s, ib], 0), 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda s, h, ib, tab, qp: (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, G, D), q.dtype),
-        interpret=interpret,
-    )(block_tables, q_pos, q, k_pool, v_pool)
+    return paged_verify_attention_fwd(
+        q[:, None], k_pool, v_pool, block_tables, q_pos, _one_token(q_pos),
+        window=window, interpret=interpret, fp8=fp8)[:, 0]
 
 
 def decode_attention_fwd(q, k, v, pos, q_pos, *, window: int = 0,

@@ -1,11 +1,10 @@
 """Jitted wrappers for the decode attention Pallas kernels.
 
 ``interpret`` defaults to *backend-selected* via
-``repro.kernels.common``: the Pallas interpreter is only used on CPU
-hosts (where Mosaic cannot compile); on TPU the kernels compile.
-``REPRO_PALLAS_INTERPRET=0|1`` force-overrides the selection, and
-``pallas_mode()`` reports the resolved mode so benchmarks can record
-which path actually ran.  (``default_interpret``/``pallas_mode`` are
+``repro.kernels.common``: the Pallas interpreter runs only on a CPU
+backend (where Mosaic cannot compile); elsewhere the kernels compile, and
+``pallas_mode()`` reports the resolved mode so reports can record which
+path actually ran.  (``default_interpret``/``pallas_mode`` are
 re-exported here for backward compatibility — ``repro.kernels.common``
 is the canonical home.)
 """

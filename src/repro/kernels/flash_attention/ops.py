@@ -3,7 +3,7 @@
 ``interpret`` defaults to *backend-selected* via ``repro.kernels.common``:
 the kernel body runs under the Pallas interpreter on CPU hosts (same
 arithmetic, Python-speed — what the correctness sweeps use) and compiles
-through Mosaic on TPU.  ``REPRO_PALLAS_INTERPRET=0|1`` force-overrides.
+through Mosaic on TPU.
 """
 from __future__ import annotations
 

@@ -2,9 +2,8 @@
 flattened LANE-padded (1, M) kernel views inside.
 
 ``interpret`` defaults to *backend-selected* via
-``repro.kernels.common``: interpret on CPU hosts (Mosaic cannot
-compile), compiled on TPU, force-overridable via
-``REPRO_PALLAS_INTERPRET=0|1``.
+``repro.kernels.common``: interpreted on a CPU backend (Mosaic cannot
+compile there), compiled everywhere else.
 
 Zero padding is invisible to the update: padded lanes carry g=m=v=p=0, so
 m'=v'=0 and u = -lr*(0/(0+eps) + 0) = 0, and they are sliced away anyway.

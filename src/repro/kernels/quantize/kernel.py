@@ -1,33 +1,35 @@
-"""Fused symmetric quantization kernels for the outer-sync transport and
-the quantized paged-KV cache.
+"""Fused symmetric quantization kernels for the outer-sync transport.
 
-One parameterized kernel pair backs every quantized wire/pool in the repo
-(``Int8Symmetric`` / ``Fp8Codec`` in ``repro.core.transport``, the fp8/int8
-KV pools in ``serving``):
+One parameterized kernel pair backs every quantized wire in the repo
+(``Int8Symmetric`` / ``Fp8Codec`` in ``repro.core.transport``):
 
-* ``quantize_ef_fwd`` — fused quantize + error-feedback residual update,
-  parameterized over the target dtype and the scale granularity.  Each
-  grid program computes the amax scale of ITS block, the clipped (and,
-  for int targets, rounded) narrow payload, AND the new residual
-  ``e - q*scale`` in a single VMEM-resident pass, where ``e = delta +
-  residual`` is the error-compensated delta.  Unfused XLA does this as
-  abs/max/div/round/clip/convert/mul/sub over separate HBM round-trips;
-  the kernel makes the fusion structural.
-* ``dequantize_fwd`` — narrow payload × per-block scale -> f32, tiled to
-  match whichever granularity produced the scales.
+* ``quantize_ef_fwd`` — quantize + error-feedback residual update with one
+  scale per worker row.  A per-row scale needs the row's global amax
+  before any element can be quantized, so it runs in two passes: an XLA
+  reduction of ``|x + residual|`` per row (fused, reads both operands
+  once, never materializes ``e``), then a tiled Pallas pass that forms
+  ``e = x + residual`` in VMEM and writes the narrow payload AND the new
+  residual ``e - q*scale`` together.
+* ``dequantize_fwd`` — narrow payload × per-row scale -> f32.
 
-Supported target dtypes × scale granularities (``QMAX`` is the symmetric
-clip bound; scale = max(amax, eps) / QMAX):
+Both kernels work on a ``(K, R, LANE)`` row view of the flattened leaf and
+sweep ``(1, rows, LANE)`` blocks, so every block fills whole (sublane,
+lane) tiles whatever K and the leaf size are, and VMEM use per step is
+bounded by ``ROWS`` (a whole-leaf block of a d20 MLP matrix would need
+over 400 MiB).
 
-    dtype      QMAX     payload        granularity
-    int8       127      round+clip     per-tensor row (tile=M) or per-tile
-    fp8_e4m3   448      clip+RNE cast  per-tensor row (tile=M) or per-tile
-    fp8_e5m2   57344    clip+RNE cast  per-tensor row (tile=M) or per-tile
+Supported target dtypes (``QMAX`` is the symmetric clip bound; scale =
+max(amax, eps) / QMAX):
 
-Per-tensor rows are whole (1, M) blocks so the amax reduction needs no
-cross-program pass; per-tile runs grid (K, M//tile) with one scale per
-(row, tile).  fp8 targets clip to ±QMAX *before* the cast: e4m3fn has no
-inf encoding, so an unclipped overflow would become NaN on the wire.
+    dtype      QMAX     payload
+    int8       127      round+clip
+    fp8_e4m3   448      clip+RNE cast
+    fp8_e5m2   57344    clip+RNE cast
+
+fp8 targets clip to ±QMAX *before* the cast: e4m3fn has no inf encoding,
+so an unclipped overflow would become NaN on the wire.  The arithmetic is
+the oracle's (``ref.reference_quantize_ef``) op for op, so the results are
+bit-identical to it.
 """
 from __future__ import annotations
 
@@ -37,7 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANE = 128          # TPU lane width: flattened payloads pad to a multiple
+LANE = 128          # TPU lane width: row views are (K, R, LANE)
+ROWS = 512          # sublane rows per grid step (256 KiB of f32 per block)
 SCALE_EPS = 1e-12   # matches the jnp oracle: scale = max(amax, eps) / QMAX
 
 # symmetric clip bound per target dtype (the finfo/iinfo max of each)
@@ -57,80 +60,73 @@ def target_dtype(dtype: str):
                      f"expected one of {QDTYPES}")
 
 
-def _quantize_ef_kernel(x_ref, r_ref, q_ref, nr_ref, s_ref, *, dtype: str):
+def block_rows(r: int) -> int:
+    """Rows per grid step for an R-row view: the whole view when it is
+    small (a block dim equal to the array dim is always legal), else
+    ``ROWS`` — callers pad R to a multiple of it."""
+    return r if r <= ROWS else ROWS
+
+
+def _quantize_ef_kernel(s_ref, x_ref, r_ref, q_ref, nr_ref, *, dtype: str):
+    scale = s_ref[0]                                  # (1, 1)
     e = x_ref[...].astype(jnp.float32) + r_ref[...].astype(jnp.float32)
     qmax = QMAX[dtype]
-    amax = jnp.max(jnp.abs(e))
-    scale = jnp.maximum(amax, SCALE_EPS) / qmax
     y = e / scale
     if dtype == "int8":
         y = jnp.round(y)
     q = jnp.clip(y, -qmax, qmax).astype(q_ref.dtype)
     q_ref[...] = q
     nr_ref[...] = e - q.astype(jnp.float32) * scale
-    s_ref[...] = jnp.full((1, 1), scale, jnp.float32)
 
 
-def _dequantize_kernel(q_ref, s_ref, o_ref):
-    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+def _dequantize_kernel(s_ref, q_ref, o_ref):
+    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0]
 
 
-def quantize_ef_fwd(x, residual, *, dtype: str = "int8", tile: int = 0,
+def _specs(K: int, R: int):
+    rb = block_rows(R)
+    assert R % rb == 0, (R, rb)
+    grid = (K, R // rb)
+    scale = pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0))
+    rows = pl.BlockSpec((1, rb, LANE), lambda i, j: (i, j, 0))
+    return grid, scale, rows
+
+
+def quantize_ef_fwd(x, residual, *, dtype: str = "int8",
                     interpret: bool = True):
-    """x, residual: (K, M) f32 with M % LANE == 0.
+    """x, residual: (K, R, LANE) f32 row views (R a multiple of
+    ``block_rows(R)``; zero padding is invisible: it adds nothing to the
+    amax, quantizes to 0 and leaves a 0 residual).
 
-    ``tile`` selects the scale granularity: 0 (the default) is per-tensor
-    (one scale per worker row, tile = M); otherwise one scale per
-    ``tile``-wide column block (M % tile == 0, tile % LANE == 0).
-
-    Returns ``(q, new_residual, scale)``: the narrow payload (K, M), the
-    f32 residual (K, M), and the f32 scales (K, M // tile).
-    """
-    K, M = x.shape
-    assert M % LANE == 0, (K, M)
-    if not tile:
-        tile = M
-    assert M % tile == 0 and tile % LANE == 0, (M, tile)
-    n_t = M // tile
-    return pl.pallas_call(
+    Returns ``(q, new_residual, scale)``: the narrow payload (K, R, LANE),
+    the f32 residual (K, R, LANE) and the f32 per-row scales (K,)."""
+    K, R, _ = x.shape
+    x = x.astype(jnp.float32)
+    residual = residual.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(x + residual), axis=(1, 2))
+    scale = jnp.maximum(amax, SCALE_EPS) / QMAX[dtype]
+    grid, s_spec, rows = _specs(K, R)
+    q, nr = pl.pallas_call(
         functools.partial(_quantize_ef_kernel, dtype=dtype),
-        grid=(K, n_t),
-        in_specs=[pl.BlockSpec((1, tile), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, tile), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((1, tile), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, tile), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j: (i, j))],
-        out_shape=[jax.ShapeDtypeStruct((K, M), target_dtype(dtype)),
-                   jax.ShapeDtypeStruct((K, M), jnp.float32),
-                   jax.ShapeDtypeStruct((K, n_t), jnp.float32)],
+        grid=grid,
+        in_specs=[s_spec, rows, rows],
+        out_specs=[rows, rows],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, target_dtype(dtype)),
+                   jax.ShapeDtypeStruct(x.shape, jnp.float32)],
         interpret=interpret,
-    )(x, residual)
+    )(scale.reshape(K, 1, 1), x, residual)
+    return q, nr, scale
 
 
-def dequantize_fwd(q, scale, *, bc: int = 0, interpret: bool = True):
-    """q: (K, M) narrow payload, scale: (K, S) f32 with M % S == 0 ->
-    f32 (K, M).  S == 1 is the per-tensor layout; S > 1 per-tile (the
-    column-block width is M // S)."""
-    K, M = q.shape
-    S = scale.shape[1]
-    assert M % LANE == 0 and M % S == 0, (K, M, S)
-    if S > 1:
-        bc = M // S              # tile width is dictated by the scales
-    elif not bc:
-        bc = M
-        for cand in (65536, 32768, 16384, 8192, 4096, 2048, 1024, 512, 256,
-                     LANE):
-            if M % cand == 0:
-                bc = cand
-                break
+def dequantize_fwd(q, scale, *, interpret: bool = True):
+    """q: (K, R, LANE) narrow payload, scale: (K,) f32 -> f32 (K, R, LANE)."""
+    K, R, _ = q.shape
+    grid, s_spec, rows = _specs(K, R)
     return pl.pallas_call(
         _dequantize_kernel,
-        grid=(K, M // bc),
-        in_specs=[pl.BlockSpec((1, bc), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, 1),
-                               (lambda i, j: (i, j)) if S > 1 else
-                               (lambda i, j: (i, 0)))],
-        out_specs=pl.BlockSpec((1, bc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((K, M), jnp.float32),
+        grid=grid,
+        in_specs=[s_spec, rows],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         interpret=interpret,
-    )(q, scale)
+    )(scale.astype(jnp.float32).reshape(K, 1, 1), q)

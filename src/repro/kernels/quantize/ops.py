@@ -1,23 +1,19 @@
 """Jitted wrappers for the quantize kernels: arbitrary leaf shapes in,
-flattened LANE-padded (K, M) kernel views inside.
+flattened zero-padded ``(K, R, LANE)`` row views inside.
 
 Public surface (all parameterized over ``dtype`` in ``kernel.QDTYPES``):
 
-* ``quantize_ef(x, residual, dtype=, tile=)`` — fused quantize +
-  error-feedback residual; ``tile=0`` is per-tensor-per-row scales,
-  ``tile>0`` one scale per ``tile`` flattened elements;
-* ``dequantize(q, scale)`` — the inverse; granularity is inferred from
-  the scale shape.
+* ``quantize_ef(x, residual, dtype=)`` — quantize + error-feedback
+  residual with one scale per worker row (per-tensor-per-worker);
+* ``dequantize(q, scale)`` — the inverse.
 
 Degenerate leaves are handled here, NOT in the kernels: scalar (0-d)
 params run through a (1, 1) view and 0-size sentinel leaves skip the
 kernel entirely (both mirror the ``ref`` oracles bit-for-bit), so codecs
 can map over any parameter pytree.
 
-``interpret`` defaults to *backend-selected* via
-``repro.kernels.common``: interpret on CPU hosts (Mosaic cannot
-compile), compiled on TPU, force-overridable via
-``REPRO_PALLAS_INTERPRET=0|1``.
+``interpret`` defaults to backend-selected via ``repro.kernels.common``:
+interpreted on a CPU backend, compiled everywhere else.
 """
 from __future__ import annotations
 
@@ -29,85 +25,74 @@ import jax.numpy as jnp
 
 from repro.kernels.common import (default_interpret, pallas_mode,
                                   resolve_interpret)
-from repro.kernels.quantize.kernel import (LANE, dequantize_fwd,
-                                           quantize_ef_fwd, target_dtype)
+from repro.kernels.quantize.kernel import (LANE, block_rows, dequantize_fwd,
+                                           quantize_ef_fwd)
 
 __all__ = ["quantize_ef", "dequantize", "default_interpret", "pallas_mode"]
 
 
-def _flatten_pad(x, multiple: int = LANE) -> Tuple[jax.Array, int]:
-    """(K, ...) -> (K, M) with M padded to a ``multiple`` multiple.
-
-    Zero padding is invisible to the kernel: padded lanes contribute 0 to
-    the amax, quantize to 0, and leave a 0 residual.
-    """
+def _rows(x) -> Tuple[jax.Array, int]:
+    """(K, ...) -> zero-padded (K, R, LANE) row view, R a multiple of
+    ``block_rows(R)``; also returns the unpadded per-row element count."""
     k = x.shape[0]
     flat = x.reshape(k, -1)
-    pad = (-flat.shape[1]) % multiple
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    return flat, x.size // k
+    m = flat.shape[1]
+    r = -(-m // LANE)
+    r = -(-r // block_rows(r)) * block_rows(r)
+    if r * LANE != m:
+        flat = jnp.pad(flat, ((0, 0), (0, r * LANE - m)))
+    return flat.reshape(k, r, LANE), m
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "tile", "interpret"))
-def _quantize_ef(x, residual, *, dtype: str, tile: int, interpret: bool):
-    xf, m = _flatten_pad(x.astype(jnp.float32), multiple=tile or LANE)
-    rf = (jnp.zeros_like(xf) if residual is None
-          else _flatten_pad(residual.astype(jnp.float32),
-                            multiple=tile or LANE)[0])
-    q, nr, s = quantize_ef_fwd(xf, rf, dtype=dtype, tile=tile,
-                               interpret=interpret)
-    shape = x.shape
-    q = q[:, :m].reshape(shape)
-    nr = nr[:, :m].reshape(shape)
-    if not tile:
-        s = s.reshape((shape[0],) + (1,) * (len(shape) - 1))
-    return q, nr, s
+def _unrows(y, m: int, shape):
+    return y.reshape(shape[0], -1)[:, :m].reshape(shape)
 
 
-def quantize_ef(x, residual=None, *, dtype: str = "int8", tile: int = 0,
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+def _quantize_ef(x, residual, *, dtype: str, interpret: bool):
+    xr, m = _rows(x.astype(jnp.float32))
+    rr = (jnp.zeros_like(xr) if residual is None
+          else _rows(residual.astype(jnp.float32))[0])
+    q, nr, s = quantize_ef_fwd(xr, rr, dtype=dtype, interpret=interpret)
+    return (_unrows(q, m, x.shape), _unrows(nr, m, x.shape),
+            s.reshape((x.shape[0],) + (1,) * (x.ndim - 1)))
+
+
+def quantize_ef(x, residual=None, *, dtype: str = "int8",
                 interpret: Optional[bool] = None):
-    """Fused per-worker-row symmetric quantize + residual update.
+    """Per-worker-row symmetric quantize + residual update.
 
     ``x``: (K, ...) delta; ``residual``: matching error-feedback carry (or
     None for plain quantization); ``dtype``: int8 / fp8_e4m3 / fp8_e5m2.
-    ``tile=0`` (per-tensor) returns results shaped like the jnp oracle
-    (``ref.reference_quantize_ef``); ``tile>0`` returns per-tile scales
-    ``(K, padded_M // tile)`` over the flattened, zero-padded row layout.
+    Returns ``(q, new_residual, scale)`` shaped like the jnp oracle
+    (``ref.reference_quantize_ef``), with which it agrees bit for bit.
     """
     interpret = resolve_interpret(interpret)
     if x.ndim == 0:                      # scalar param: quantize elementwise
         q, nr, s = _quantize_ef(
             x.reshape(1, 1),
             None if residual is None else residual.reshape(1, 1),
-            dtype=dtype, tile=tile, interpret=interpret)
+            dtype=dtype, interpret=interpret)
         return q.reshape(()), nr.reshape(()), s.reshape(())
     if x.size == 0:                      # 0-size sentinel leaf: no kernel
         from repro.kernels.quantize.ref import reference_quantize_ef
         return reference_quantize_ef(x, residual, dtype=dtype)
-    return _quantize_ef(x, residual, dtype=dtype, tile=tile,
-                        interpret=interpret)
+    return _quantize_ef(x, residual, dtype=dtype, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _dequantize(q, scale, *, tile: int, interpret: bool):
-    k = q.shape[0]
-    qf, m = _flatten_pad(q, multiple=tile or LANE)
-    s = scale.reshape(k, 1) if not tile else scale.reshape(k, -1)
-    out = dequantize_fwd(qf, s, interpret=interpret)
-    return out[:, :m].reshape(q.shape)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dequantize(q, scale, *, interpret: bool):
+    qr, m = _rows(q)
+    out = dequantize_fwd(qr, scale.reshape(q.shape[0]), interpret=interpret)
+    return _unrows(out, m, q.shape)
 
 
-def dequantize(q, scale, *, tile: int = 0,
-               interpret: Optional[bool] = None):
-    """Narrow (K, ...) payload x scales -> f32 delta.  ``tile`` must match
-    the granularity ``quantize_ef`` ran with: 0 for per-tensor rows
-    (scales of size K), else the per-tile width (scales
-    ``(K, padded_M // tile)``)."""
+def dequantize(q, scale, *, interpret: Optional[bool] = None):
+    """Narrow (K, ...) payload x per-row scales (K, 1, ..., 1) -> f32."""
     interpret = resolve_interpret(interpret)
     if q.ndim == 0:
         return _dequantize(q.reshape(1, 1), scale.reshape(1, 1),
-                           tile=0, interpret=interpret).reshape(())
+                           interpret=interpret).reshape(())
     if q.size == 0:
         return q.astype(jnp.float32)
-    return _dequantize(q, scale, tile=tile, interpret=interpret)
+    return _dequantize(q, scale, interpret=interpret)

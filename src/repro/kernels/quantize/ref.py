@@ -10,9 +10,8 @@ The dtype × granularity matrix mirrors ``kernel.QMAX``:
 * ``reference_quantize_ef``   — per-tensor-per-worker scales (reduce over
   every non-leading axis), int8 / fp8_e4m3 / fp8_e5m2 targets, fused
   error-feedback residual;
-* ``reference_quantize_axis`` — per-tile scales (reduce over ONE axis,
-  keepdims), the oracle for the per-tile kernel path and the primitive
-  the quantized KV pool quantizes heads with;
+* ``reference_quantize_axis`` — per-slice scales (reduce over ONE axis,
+  keepdims), the primitive the quantized KV pool quantizes heads with;
 * ``reference_dequantize``    — payload × broadcastable scale -> f32.
 """
 from __future__ import annotations
@@ -61,10 +60,10 @@ def reference_quantize_ef(x, residual=None, dtype: str = "int8"):
 
 
 def reference_quantize_axis(x, axis: int = -1, dtype: str = "fp8_e4m3"):
-    """Per-tile symmetric quantization: one amax scale per slice along
-    ``axis`` (keepdims).  No error feedback — this is the oracle for the
-    per-tile kernel path and the KV-pool append primitive (axis = head
-    dim -> per-token-per-head scales).  Returns ``(q, scale)``.
+    """Per-slice symmetric quantization: one amax scale per slice along
+    ``axis`` (keepdims).  No error feedback — this is the KV-pool append
+    primitive (axis = head dim -> per-token-per-head scales).  Returns
+    ``(q, scale)``.
     """
     e = x.astype(jnp.float32)
     if e.size == 0:

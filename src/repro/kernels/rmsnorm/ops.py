@@ -1,8 +1,7 @@
 """Jitted wrappers for the fused RMSNorm kernel (reshape any leading dims).
 
 ``interpret`` defaults to *backend-selected* via ``repro.kernels.common``:
-interpret on CPU hosts, compiled on TPU, ``REPRO_PALLAS_INTERPRET=0|1``
-force-overrides.
+interpreted on a CPU backend, compiled everywhere else.
 """
 from __future__ import annotations
 

@@ -1,8 +1,7 @@
 """Jitted wrapper for the SSD Pallas kernel (pads S to a chunk multiple).
 
 ``interpret`` defaults to *backend-selected* via ``repro.kernels.common``:
-interpret on CPU hosts, compiled on TPU, ``REPRO_PALLAS_INTERPRET=0|1``
-force-overrides.
+interpreted on a CPU backend, compiled everywhere else.
 """
 from __future__ import annotations
 

@@ -11,18 +11,11 @@ state (the dry-run launcher must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit mesh axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax (e.g. 0.4.37): meshes are Auto by default
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, num_pods: int = 2):

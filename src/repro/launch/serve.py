@@ -62,6 +62,9 @@ def main(argv=None):
     ap.add_argument("--config", type=str, default="tiny",
                     help="arch name fallback when the checkpoint has no "
                          ".cfg.json metadata")
+    ap.add_argument("--reduced", action="store_true",
+                    help="build the 2-layer CPU-sized variant of --config "
+                         "instead of its published widths")
     ap.add_argument("--prompt", action="append", default=[])
     ap.add_argument("--stream", type=str, default=None,
                     help="JSONL request stream with arrival timestamps")
@@ -101,6 +104,8 @@ def main(argv=None):
                     help="print per-request latency + aggregate tokens/s")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from repro.checkpoint import load_config
     from repro.launch.train import build_pipeline, make_model
     from repro.models import build_model
@@ -112,15 +117,15 @@ def main(argv=None):
     if cfg is not None:
         print(f"# model config from checkpoint metadata: {cfg.name}")
     else:
-        cfg, _ = make_model(args.config, True, tok.vocab_size)
+        cfg, _ = make_model(args.config, args.reduced, tok.vocab_size)
     if args.kv_dtype is not None:
         # the pool format is a serving decision: override whatever the
         # checkpoint metadata says BEFORE the engine reads model.cfg
         cfg = cfg.with_(kv_cache_dtype=args.kv_dtype
                         if args.kv_dtype != "f32" else "float32")
     model = build_model(cfg)
-    if cfg.vocab_size != tok.vocab_size:
-        print(f"# warning: checkpoint vocab {cfg.vocab_size} != pipeline "
+    if cfg.vocab_size < tok.vocab_size:
+        print(f"# warning: model vocab {cfg.vocab_size} < pipeline "
               f"tokenizer vocab {tok.vocab_size}", file=sys.stderr)
     params, _ = init_params(cfg, jax.random.key(args.seed))
     if args.ckpt:

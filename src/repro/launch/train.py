@@ -25,10 +25,10 @@ run, the comm simulator replays the sync schedule with per-worker step
 clocks (calibrated from the measured inner-step seconds of the base
 stage) and reports the modeled homogeneous vs heterogeneous wall-clock.
 
-On this CPU container the model is a reduced nanochat-style config and the
-corpora are synthetic (see repro.data.synthetic); on a TPU fleet the same
-entry point drives the production mesh (--arch picks any registered
-architecture, DiLoCo workers map to pods).
+The corpora are synthetic (see repro.data.synthetic).  ``--arch tiny``
+(the default) is a 4-layer toy for CPU runs; ``--arch <name>`` builds any
+registered architecture at its published widths and vocab, and
+``--reduced`` asks for its 2-layer CPU-sized variant instead.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --method diloco --steps 200
@@ -76,9 +76,13 @@ def make_model(arch: str, reduced: bool, vocab_size: int):
         cfg = ModelConfig(name="tiny-nanochat", num_layers=4, d_model=128,
                           num_heads=4, num_kv_heads=4, d_ff=512,
                           vocab_size=vocab_size, tie_embeddings=True)
+    elif reduced:
+        cfg = get_reduced(arch).with_(vocab_size=vocab_size)
     else:
-        cfg = get_reduced(arch) if reduced else get_config(arch)
-        cfg = cfg.with_(vocab_size=vocab_size)
+        # a published config keeps its own vocab: the tokenizer's ids are
+        # a subset of it (it grows only for a tokenizer that needs more)
+        cfg = get_config(arch)
+        cfg = cfg.with_(vocab_size=max(cfg.vocab_size, vocab_size))
     return cfg, build_model(cfg)
 
 
@@ -91,7 +95,8 @@ def run_stage(method: str, model, params, stage_ds, *, steps: int,
               resume: bool = False):
     """Run one pipeline stage under any sync strategy; returns
     (final params, history).  All methods go through the unified
-    ``DistTrainer`` runtime — ``method`` picks the ``SyncStrategy``."""
+    ``DistTrainer`` runtime — ``method`` picks the ``SyncStrategy``.
+    ``params`` are consumed (donated to the run): use the returned ones."""
     import dataclasses
     import jax.numpy as jnp
     from repro.core import DistTrainer, make_strategy
@@ -132,12 +137,16 @@ def run_stage(method: str, model, params, stage_ds, *, steps: int,
 
     trainer = DistTrainer(model.loss, opt_cfg, dcfg,
                           make_strategy(dcfg, h_schedule=h_schedule))
-    state = trainer.init(params)
-    state, hist = trainer.run(state, data, steps, prefetch=prefetch,
-                              faults=faults, min_quorum=min_quorum,
+    # the stage owns its trainer state: hand it to the run without the
+    # defensive copy (at published widths two copies of params + outer +
+    # optimizer state do not fit one chip).  The state's anchor IS
+    # ``params``, so the caller's ``params`` are consumed too.
+    state, hist = trainer.run(trainer.init(params), data, steps,
+                              prefetch=prefetch, faults=faults,
+                              min_quorum=min_quorum,
                               checkpoint_dir=checkpoint_dir,
                               checkpoint_every=checkpoint_every,
-                              resume=resume)
+                              resume=resume, consume=True)
     return state.global_params, hist
 
 
@@ -181,7 +190,7 @@ def comm_report(dcfg, method: str, n_params: int, steps: int, h: int,
 
 
 def run_pipeline(method: str = "diloco", arch: str = "tiny",
-                 reduced: bool = True, steps: Dict[str, int] = None,
+                 reduced: bool = False, steps: Dict[str, int] = None,
                  workers: int = 4, per_worker_batch: int = 8,
                  seq_len: int = 128, adaptive_h: bool = False,
                  delta_dtype: str = "float32", grad_compress: str = "none",
@@ -315,12 +324,15 @@ def run_pipeline(method: str = "diloco", arch: str = "tiny",
 
 def main(argv=None):
     from repro.core import strategy_names
+    from repro.launch.compile_cache import setup_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--method",
                     choices=list(strategy_names()) + ["hybrid"],
                     default="diloco")
     ap.add_argument("--arch", type=str, default="tiny")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="build the 2-layer CPU-sized variant of --arch "
+                         "instead of its published widths")
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--adaptive-h", action="store_true")
@@ -384,6 +396,7 @@ def main(argv=None):
     ap.add_argument("--out-dir", type=str, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
     canon = {"f32": "float32", "bf16": "bfloat16", "int8": "int8",
              "fp8": "fp8", "e5m2": "fp8_e5m2", "fp8_e5m2": "fp8_e5m2",
              "float32": "float32", "bfloat16": "bfloat16"}

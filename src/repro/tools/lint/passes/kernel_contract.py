@@ -6,8 +6,8 @@ Every kernel package must
    ``kernel.py`` (the Pallas kernel), ``ref.py`` (the jnp oracle);
 2. resolve its interpret default through the shared helper
    (``from repro.kernels.common import default_interpret/resolve_interpret``)
-   rather than a private copy — one ``REPRO_PALLAS_INTERPRET`` override
-   point for the whole repo;
+   rather than a private copy — one backend-derived interpret selection
+   for the whole repo;
 3. be exercised by at least one test under ``tests/`` that imports its
    ``reference_*`` oracle (or the ``ref`` module) — the kernel-vs-oracle
    comparison is the repo's correctness contract for compiled TPU runs.
@@ -125,9 +125,9 @@ class KernelContractPass(LintPass):
                     path=str(ops), line=node.lineno, col=node.col_offset,
                     pass_name=self.name,
                     message=(f"ops.py defines a private '{node.name}'; "
-                             f"use the shared copy in {_COMMON} so "
-                             f"REPRO_PALLAS_INTERPRET has one override "
-                             f"point")))
+                             f"use the shared copy in {_COMMON} so the "
+                             f"interpret selection stays backend-derived "
+                             f"in one place")))
         if not imports_common:
             out.append(Violation(
                 path=str(ops), line=1, col=0, pass_name=self.name,
