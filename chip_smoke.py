@@ -19,10 +19,10 @@ Phases, all in this one process (it is the only one that holds the chip):
   and fp8 pools, and fp8 QK^T tiles) against their ``ref.py`` oracles at
   d20 shapes, and the quantize kernels against theirs on a d20 leaf.
 
-Lines starting with ``smoke:`` are smoke observations (compile seconds,
-steady step seconds, ``peak_bytes_in_use``, the Pallas mode), not
-benchmark metrics.  The last line is ``{"ok": true, "device": {...}}``;
-any failure exits non-zero before it is printed.
+Lines starting with ``smoke:`` are smoke observations (wall seconds per
+phase, ``peak_bytes_in_use``, the Pallas mode), not benchmark metrics.
+The last line is ``{"ok": true, "device": {...}}``; any failure exits
+non-zero before it is printed.
 """
 from __future__ import annotations
 
@@ -61,40 +61,14 @@ def report(phase, **obs):
     print("smoke: " + json.dumps({"phase": phase, **obs}), flush=True)
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling (or loading
-    from the persistent cache), from JAX's own monitoring events.  A
-    nested jit is traced inside its caller's trace, so the events
-    overlap: the clock counts the union of their spans."""
-
-    def __init__(self):
-        import jax
-        self.spans = []
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event.startswith("/jax/core/compile/"):
-            end = time.perf_counter()
-            self.spans.append((end - duration, end))
-
-    @property
-    def seconds(self):
-        total, reach = 0.0, float("-inf")
-        for start, end in sorted(self.spans):
-            if end > reach:
-                total += end - max(start, reach)
-                reach = end
-        return total
-
-
 class Phase:
-    """Wall and compile seconds and the device's peak bytes of one phase."""
+    """Wall seconds and the device's peak bytes of one phase."""
 
-    def __init__(self, name, clock, dev):
-        self.name, self.clock, self.dev = name, clock, dev
+    def __init__(self, name, dev):
+        self.name, self.dev = name, dev
 
     def __enter__(self):
-        self.c0, self.t0 = self.clock.seconds, time.perf_counter()
+        self.t0 = time.perf_counter()
         self.obs = {}
         return self
 
@@ -103,7 +77,6 @@ class Phase:
             from repro.kernels.common import pallas_mode
             stats = self.dev.memory_stats() or {}
             report(self.name, wall_s=time.perf_counter() - self.t0,
-                   compile_s=self.clock.seconds - self.c0,
                    peak_bytes_in_use=stats.get("peak_bytes_in_use"),
                    pallas_mode=pallas_mode(), **self.obs)
 
@@ -112,7 +85,7 @@ class Phase:
 # train
 # ---------------------------------------------------------------------------
 
-def train_phase(method, model, params, ds, seed, clock, dev):
+def train_phase(method, model, params, ds, seed, dev):
     """A warm-up stage (compiles the chunk and outer programs, which the
     persistent cache then holds), then a timed stage.  Returns the
     trained params and every loss."""
@@ -127,17 +100,12 @@ def train_phase(method, model, params, ds, seed, clock, dev):
     # share one compiled chunk; diloco chunks end at every outer sync
     timed = H if method == "ddp" else 2 * H
     for tag, steps in (("warm", H), ("timed", timed)):
-        with Phase(f"train_{method}_{tag}", clock, dev) as ph:
+        with Phase(f"train_{method}_{tag}", dev) as ph:
             params, hist = run_stage(method, model, params, ds, steps=steps,
                                      workers=1, per_worker_batch=BATCH, h=H,
                                      opt_cfg=opt, diloco_cfg=dcfg, seed=seed)
             jax.block_until_ready(params)
-            # each stage builds fresh jits: take compile (or cache-load)
-            # seconds out of the stage's wall time before dividing
-            run_s = (time.perf_counter() - ph.t0
-                     - (clock.seconds - ph.c0))
             ph.obs = {"steps": steps, "tokens_per_step": BATCH * SEQ,
-                      "step_s_excl_compile": run_s / steps,
                       "loss": hist["loss"],
                       "outer_syncs": len(hist["sync_steps"])
                       if method == "diloco" else 0}
@@ -195,7 +163,7 @@ def reference_last_logits(model, params, prompts):
         return np.asarray(f(params, jnp.asarray(toks)))
 
 
-def serve_phase(model, tokens, seed, clock, dev):
+def serve_phase(model, tokens, seed, dev):
     import jax
     import numpy as np
     from repro.models.transformer import init_params
@@ -204,27 +172,24 @@ def serve_phase(model, tokens, seed, clock, dev):
     cfg = model.cfg
     params, _ = init_params(cfg, jax.random.key(seed + 1))
     prompts = [r.prompt for r in make_requests(tokens, cfg.vocab_size, seed)]
-    with Phase("serve_reference", clock, dev):
+    with Phase("serve_reference", dev):
         ref = reference_last_logits(model, params, prompts)
     check(np.isfinite(ref).all(), "reference logits are not finite")
     outs = {}
     for k in (0, 4):
-        with Phase(f"serve_spec{k}", clock, dev) as ph:
+        with Phase(f"serve_spec{k}", dev) as ph:
             eng = Engine(model, params, max_len=MAX_LEN, num_slots=SLOTS,
                          block_size=BLOCK, num_blocks=POOL_BLOCKS, spec_k=k)
             check(eng.attn_impl == "pallas",
                   f"engine picked attention {eng.attn_impl!r}, not pallas")
             reqs = make_requests(tokens, cfg.vocab_size, seed)
-            c0 = clock.seconds
             stats = eng.run(reqs)
-            run_s = stats["wall"] - (clock.seconds - c0)
             gaps = [float(ref[i].max() - ref[i][r.tokens[0]])
                     for i, r in enumerate(reqs)]
             ph.obs = {"requests": len(reqs),
                       "prompt_tokens": [len(r.prompt) for r in reqs],
                       "generated": stats["generated"],
                       "step_calls": stats["step_calls"],
-                      "step_s_excl_compile": run_s / stats["step_calls"],
                       "first_token_logit_gap": gaps,
                       "attn_impl": eng.attn_impl}
             del eng             # frees the KV pool before the next engine
@@ -274,7 +239,7 @@ def paged_case(key, fmt, T):
     return q, kp, vp, jnp.asarray(tables), jnp.asarray(start)
 
 
-def kernel_phase(clock, dev):
+def kernel_phase(dev):
     import jax
     import jax.numpy as jnp
     from repro.kernels import decode_attention as da
@@ -284,7 +249,7 @@ def kernel_phase(clock, dev):
                                         reference_quantize_ef)
 
     errs = {}
-    with Phase("kernels", clock, dev) as ph:
+    with Phase("kernels", dev) as ph:
         # pool format -> (kernel suffix, oracle suffix); fp8_qk is an f32
         # pool with fp8 QK^T tiles (ModelConfig.fp8_matmul)
         formats = {"float32": ("", ""), "bfloat16": ("", ""),
@@ -368,7 +333,6 @@ def main(argv=None) -> int:
     report("device", platform=dev.platform, kind=dev.device_kind,
            count=len(jax.devices()))
     cache = setup_compile_cache()
-    clock = CompileClock()
     from repro.configs import get_config
     from repro.launch.train import build_pipeline, make_model
     from repro.models import build_model
@@ -389,17 +353,16 @@ def main(argv=None) -> int:
 
     params, _ = init_params(cfg, jax.random.key(args.seed))
     params, ddp_losses = train_phase("ddp", model, params, stages["base"],
-                                     args.seed, clock, dev)
+                                     args.seed, dev)
     first, ln_v = ddp_losses[0], math.log(cfg.vocab_size)
     check(abs(first - ln_v) < 1.0,
           f"first loss {first:.3f} is not near ln(vocab) = {ln_v:.3f}")
     params, _ = train_phase("diloco", model, params, stages["base"],
-                            args.seed, clock, dev)
+                            args.seed, dev)
     del params
 
-    serve_phase(model, stages["base"].tokens, args.seed, clock, dev)
-    kernel_phase(clock, dev)
-    report("total", compile_s=clock.seconds)
+    serve_phase(model, stages["base"].tokens, args.seed, dev)
+    kernel_phase(dev)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

@@ -74,11 +74,15 @@ class DiLoCoTrainer:
 
     # -- inner step ----------------------------------------------------------
     def _one_worker_step(self, params, opt_state, batch, step):
+        # ``model`` sits inside the differentiated function, so autodiff
+        # names the backward ``transpose(jvp(model))``
         (loss, metrics), grads = jax.value_and_grad(
-            self.loss_fn, has_aux=True)(params, batch)
-        updates, opt_state = self._inner_opt().update(
-            grads, opt_state, params, step)
-        return apply_updates(params, updates), opt_state, loss, metrics
+            jax.named_scope("model")(self.loss_fn), has_aux=True)(
+                params, batch)
+        with jax.named_scope("inner_opt"):
+            updates, opt_state = self._inner_opt().update(
+                grads, opt_state, params, step)
+            return apply_updates(params, updates), opt_state, loss, metrics
 
     def inner_step(self, state: DiLoCoState, batches) -> Tuple[DiLoCoState, jax.Array, Dict]:
         """batches: pytree with leading (K, ...) — one shard per worker."""
@@ -170,19 +174,22 @@ class DiLoCoTrainer:
     def outer_step_ef(self, state: DiLoCoState, residual=None):
         """Outer sync through the codec transport with an optional
         error-feedback residual; returns (new state, new residual)."""
-        delta = jax.tree.map(
-            lambda w, g: w.astype(jnp.float32) - g.astype(jnp.float32)[None],
-            state.worker_params, state.global_params)
-        avg, new_residual = outer_opt.exchange_and_average(
-            delta, self.cfg, self.replicate_fn, residual=residual)
-        new_global, new_outer = outer_opt.outer_update(
-            state.global_params, avg, state.outer, self.cfg)
-        # re-broadcast the synchronized params; inner optimizer state is kept
-        # per-worker across syncs (paper §3 — AdamW/Muon state is local)
-        new_wp = _broadcast(new_global, self.cfg.num_workers)
-        return state._replace(global_params=new_global,
-                              worker_params=new_wp,
-                              outer=new_outer), new_residual
+        with jax.named_scope("outer_step"):
+            delta = jax.tree.map(
+                lambda w, g: (w.astype(jnp.float32)
+                              - g.astype(jnp.float32)[None]),
+                state.worker_params, state.global_params)
+            avg, new_residual = outer_opt.exchange_and_average(
+                delta, self.cfg, self.replicate_fn, residual=residual)
+            new_global, new_outer = outer_opt.outer_update(
+                state.global_params, avg, state.outer, self.cfg)
+            # re-broadcast the synchronized params; inner optimizer state is
+            # kept per-worker across syncs (paper §3 — AdamW/Muon state is
+            # local)
+            new_wp = _broadcast(new_global, self.cfg.num_workers)
+            return state._replace(global_params=new_global,
+                                  worker_params=new_wp,
+                                  outer=new_outer), new_residual
 
     def outer_step(self, state: DiLoCoState) -> DiLoCoState:
         return self.outer_step_ef(state)[0]
@@ -201,34 +208,36 @@ class DiLoCoTrainer:
           init to zeros, so zeroing IS re-initialization);
         * rows in none of the masks (dead workers) pass through frozen.
         """
-        rows = outer_opt._mask_rows
-        delta = jax.tree.map(
-            lambda w, g: w.astype(jnp.float32) - g.astype(jnp.float32)[None],
-            state.worker_params, state.global_params)
-        avg, new_residual = outer_opt.exchange_and_average(
-            delta, self.cfg, self.replicate_fn, residual=residual,
-            live=contrib)
-        new_global, new_outer = outer_opt.outer_update(
-            state.global_params, avg, state.outer, self.cfg)
-        take = jnp.logical_or(adopt, reset)
-        new_wp = jax.tree.map(
-            lambda g, o: jnp.where(rows(take, o), g[None], o),
-            new_global, state.worker_params)
-        new_opt = jax.tree.map(
-            lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-            state.inner_opt)
-        if new_residual is not None:
-            # non-contributors never shipped, so their EF carry is
-            # unchanged; rejoiners restart with a clean carry
-            new_residual = jax.tree.map(
-                lambda n, o: jnp.where(
-                    rows(reset, n), jnp.zeros_like(n),
-                    jnp.where(rows(contrib, n), n, o)),
-                new_residual, residual)
-        return state._replace(global_params=new_global,
-                              worker_params=new_wp,
-                              inner_opt=new_opt,
-                              outer=new_outer), new_residual
+        with jax.named_scope("outer_step"):
+            rows = outer_opt._mask_rows
+            delta = jax.tree.map(
+                lambda w, g: (w.astype(jnp.float32)
+                              - g.astype(jnp.float32)[None]),
+                state.worker_params, state.global_params)
+            avg, new_residual = outer_opt.exchange_and_average(
+                delta, self.cfg, self.replicate_fn, residual=residual,
+                live=contrib)
+            new_global, new_outer = outer_opt.outer_update(
+                state.global_params, avg, state.outer, self.cfg)
+            take = jnp.logical_or(adopt, reset)
+            new_wp = jax.tree.map(
+                lambda g, o: jnp.where(rows(take, o), g[None], o),
+                new_global, state.worker_params)
+            new_opt = jax.tree.map(
+                lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
+                state.inner_opt)
+            if new_residual is not None:
+                # non-contributors never shipped, so their EF carry is
+                # unchanged; rejoiners restart with a clean carry
+                new_residual = jax.tree.map(
+                    lambda n, o: jnp.where(
+                        rows(reset, n), jnp.zeros_like(n),
+                        jnp.where(rows(contrib, n), n, o)),
+                    new_residual, residual)
+            return state._replace(global_params=new_global,
+                                  worker_params=new_wp,
+                                  inner_opt=new_opt,
+                                  outer=new_outer), new_residual
 
     def adopt_anchor(self, state: DiLoCoState, residual, reset):
         """Rejoin without a round (quorum skipped): ``reset`` rows adopt

@@ -59,6 +59,40 @@ default ``chunked=True`` loop makes that true:
 
 ``chunked=False`` keeps the original per-step loop — the reference the
 bit-exactness tests (and ``benchmarks/train_bench.py``) compare against.
+
+Profiling
+---------
+The chunked loop and the programs it runs name their parts for JAX's own
+profiler.  Wrap a run in ``jax.profiler.trace(log_dir)`` and open the
+``.xplane.pb`` it writes in TensorBoard's profile plugin, or its trace in
+Perfetto; the host spans and the device ops share the profiler's clock.
+
+Host spans (``jax.profiler.TraceAnnotation``), one set per chunk, inside a
+``jax.profiler.StepTraceAnnotation("train", step_num=<chunk index>)``:
+
+* ``trainer.data``       — ``data_fn`` + ``stack_batches``, or the
+  prefetcher's ``take`` (and its ``prime`` of the next chunk);
+* ``trainer.chunk``      — the ``inner_chunk`` dispatch, with args
+  ``first_step`` and ``steps``;
+* ``trainer.fetch``      — the one loss fetch, which waits on the device;
+* ``trainer.sync``       — the ``after_step`` replay, which dispatches any
+  outer step;
+* ``trainer.checkpoint`` and ``trainer.eval`` where those run.
+
+Device scopes (``jax.named_scope``), in the ops' ``op_name`` metadata:
+
+* ``model`` — the loss that ``value_and_grad`` differentiates, with
+  ``attention``, ``mlp`` (``models.transformer._block_fwd``) and
+  ``lm_head`` (the chunked cross-entropy) inside it.  Autodiff names the
+  backward ``transpose(jvp(model))``; the layer scan's remat recompute
+  runs inside that backward;
+* ``inner_opt`` — the optimizer update and its application, with
+  ``clip``, ``muon`` (``newton_schulz`` inside it) and ``adamw`` inside;
+* ``outer_step`` — the DiLoCo and Streaming DiLoCo outer-step programs
+  (``outer_step_ef``, ``outer_step_quorum`` and their fragment forms).
+
+With the profiler off a span is one inactive ``TraceMe`` and a scope is
+compile-time metadata only.
 """
 from __future__ import annotations
 
@@ -81,6 +115,9 @@ from repro.core.sync import SyncStrategy
 # the loop's single deliberate device->host read per chunk — module-level so
 # the one-fetch guard test can count calls
 _fetch = jax.device_get
+
+# a host span of the chunked loop (module docstring, "Profiling")
+_span = jax.profiler.TraceAnnotation
 
 # CPU backends ignore donation for some buffers; the advisory warning would
 # fire once per compiled chunk length.  Applied via catch_warnings inside
@@ -293,98 +330,122 @@ class DistTrainer:
 
             try:
                 step = start_step
+                chunk_index = 0
                 t_prev = time.time()
                 pending_ckpt = False
                 while step < num_steps:
-                    live = None
-                    if tracker is not None:
-                        live, recs = tracker.begin_chunk(step)
-                        record(recs)
-                    end = chunk_end(step)
-                    T = end - step + 1
-                    batches = (source.take(step, T) if source is not None
-                               else stack_batches([data_fn(s)
-                                                   for s in
-                                                   range(step, end + 1)]))
-                    if inner_live is not None and not all(live):
-                        # dead rows freeze (params + opt pass through); the
-                        # all-live path keeps the original jit program so
-                        # fault-free stretches stay bit-exact with it
-                        state, losses = inner_live(
-                            state, batches,
-                            jnp.asarray(live, jnp.bool_))
-                    else:
-                        state, losses = inner_chunk(state, batches)
-                    losses_host = _fetch(losses)    # ONE fetch per chunk
-                    for i in range(T):
-                        s = step + i
-                        loss_mean = (_host_mean(losses_host[i])
-                                     if live is None or all(live)
-                                     else _host_mean_live(losses_host[i],
-                                                          live))
-                        if s % record_every == 0:
-                            history["step"].append(s)
-                            history["loss"].append(loss_mean)
-                        new_state, recs = runner.after_step(state, s,
-                                                            loss_mean)
-                        if new_state is not state and i != T - 1:
-                            raise RuntimeError(
-                                f"sync runner replaced the state at step "
-                                f"{s}, mid-chunk (chunk ends at {end}): "
-                                f"next_event() must report every step "
-                                f"whose after_step touches device state — "
-                                f"e.g. an HSchedule that fires before "
-                                f"since_sync reaches current_h violates "
-                                f"the chunked contract; run with "
-                                f"chunked=False for such schedules")
-                        state = new_state
-                        record(recs)
-                    if source is not None and end + 1 < num_steps:
-                        # the replay above just dispatched any outer sync
-                        # asynchronously; start assembling the NEXT chunk's
-                        # batches now so the stack + device_put overlap the
-                        # sync instead of serializing behind it at the top
-                        # of the loop.  next_event is accurate here (the
-                        # runner replayed through ``end``), so the primed
-                        # bounds match the next take(); if a custom runner
-                        # shifts them anyway, take() falls back losslessly.
-                        source.prime(end + 1, chunk_end(end + 1) - end)
-                    t_now = time.time()
-                    chunk_step_seconds.append((t_now - t_prev) / T)
-                    t_prev = t_now
-                    if checkpoint_dir and checkpoint_every and (
-                            pending_ckpt
-                            or (end + 1) % checkpoint_every == 0):
-                        extras = runner.checkpoint_extras()
-                        if extras is None:
-                            # runner mid-round: its in-flight device state
-                            # isn't serializable — defer to the next clean
-                            # chunk boundary
-                            pending_ckpt = True
-                        else:
-                            pending_ckpt = False
-                            from repro.checkpoint import save_run_checkpoint
-                            arrays, extras_meta = extras
-                            save_run_checkpoint(
-                                checkpoint_dir, end + 1, _fetch(state),
-                                extras_arrays=_fetch(arrays),
-                                extras_meta=extras_meta,
-                                history=history,
-                                meta={"num_steps": num_steps})
-                            t_prev = time.time()  # ckpt IO != step time
-                    if tracker is not None and tracker.kill_at(end):
-                        # scripted process death: any due checkpoint was
-                        # just written; the finally below closes the source
-                        # and finalize() never runs — exactly a crash
-                        raise SimulatedCrash(
-                            f"scripted kill after step {end}")
-                    if (eval_fn is not None and eval_every
-                            and (end + 1) % eval_every == 0):
-                        state = runner.refresh(state)
-                        history["evals"].append(
-                            (end, eval_fn(state.global_params)))
-                        t_prev = time.time()    # eval time != step time
+                    with jax.profiler.StepTraceAnnotation(
+                            "train", step_num=chunk_index):
+                        live = None
+                        if tracker is not None:
+                            live, recs = tracker.begin_chunk(step)
+                            record(recs)
+                        end = chunk_end(step)
+                        T = end - step + 1
+                        with _span("trainer.data"):
+                            batches = (source.take(step, T)
+                                       if source is not None
+                                       else stack_batches(
+                                           [data_fn(s)
+                                            for s in range(step, end + 1)]))
+                        with _span("trainer.chunk", first_step=step,
+                                   steps=T):
+                            if inner_live is not None and not all(live):
+                                # dead rows freeze (params + opt pass
+                                # through); the all-live path keeps the
+                                # original jit program so fault-free
+                                # stretches stay bit-exact with it
+                                state, losses = inner_live(
+                                    state, batches,
+                                    jnp.asarray(live, jnp.bool_))
+                            else:
+                                state, losses = inner_chunk(state, batches)
+                        with _span("trainer.fetch"):
+                            losses_host = _fetch(losses)  # ONE per chunk
+                        with _span("trainer.sync"):
+                            for i in range(T):
+                                s = step + i
+                                loss_mean = (
+                                    _host_mean(losses_host[i])
+                                    if live is None or all(live)
+                                    else _host_mean_live(losses_host[i],
+                                                         live))
+                                if s % record_every == 0:
+                                    history["step"].append(s)
+                                    history["loss"].append(loss_mean)
+                                new_state, recs = runner.after_step(
+                                    state, s, loss_mean)
+                                if new_state is not state and i != T - 1:
+                                    raise RuntimeError(
+                                        f"sync runner replaced the state "
+                                        f"at step {s}, mid-chunk (chunk "
+                                        f"ends at {end}): next_event() "
+                                        f"must report every step whose "
+                                        f"after_step touches device state "
+                                        f"— e.g. an HSchedule that fires "
+                                        f"before since_sync reaches "
+                                        f"current_h violates the chunked "
+                                        f"contract; run with "
+                                        f"chunked=False for such "
+                                        f"schedules")
+                                state = new_state
+                                record(recs)
+                        if source is not None and end + 1 < num_steps:
+                            # the replay above just dispatched any outer
+                            # sync asynchronously; start assembling the
+                            # NEXT chunk's batches now so the stack +
+                            # device_put overlap the sync instead of
+                            # serializing behind it at the top of the
+                            # loop.  next_event is accurate here (the
+                            # runner replayed through ``end``), so the
+                            # primed bounds match the next take(); if a
+                            # custom runner shifts them anyway, take()
+                            # falls back losslessly.
+                            with _span("trainer.data"):
+                                source.prime(end + 1,
+                                             chunk_end(end + 1) - end)
+                        t_now = time.time()
+                        chunk_step_seconds.append((t_now - t_prev) / T)
+                        t_prev = t_now
+                        if checkpoint_dir and checkpoint_every and (
+                                pending_ckpt
+                                or (end + 1) % checkpoint_every == 0):
+                            extras = runner.checkpoint_extras()
+                            if extras is None:
+                                # runner mid-round: its in-flight device
+                                # state isn't serializable — defer to the
+                                # next clean chunk boundary
+                                pending_ckpt = True
+                            else:
+                                pending_ckpt = False
+                                from repro.checkpoint import (
+                                    save_run_checkpoint)
+                                arrays, extras_meta = extras
+                                with _span("trainer.checkpoint"):
+                                    save_run_checkpoint(
+                                        checkpoint_dir, end + 1,
+                                        _fetch(state),
+                                        extras_arrays=_fetch(arrays),
+                                        extras_meta=extras_meta,
+                                        history=history,
+                                        meta={"num_steps": num_steps})
+                                t_prev = time.time()  # ckpt IO != step time
+                        if tracker is not None and tracker.kill_at(end):
+                            # scripted process death: any due checkpoint
+                            # was just written; the finally below closes
+                            # the source and finalize() never runs —
+                            # exactly a crash
+                            raise SimulatedCrash(
+                                f"scripted kill after step {end}")
+                        if (eval_fn is not None and eval_every
+                                and (end + 1) % eval_every == 0):
+                            with _span("trainer.eval"):
+                                state = runner.refresh(state)
+                                history["evals"].append(
+                                    (end, eval_fn(state.global_params)))
+                            t_prev = time.time()    # eval time != step time
                     step = end + 1
+                    chunk_index += 1
             finally:
                 if source is not None:
                     source.close()

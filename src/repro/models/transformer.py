@@ -84,9 +84,10 @@ def _block_fwd(p, h, cfg: ModelConfig, positions, window, impl=None):
     if cfg.arch_type == "ssm":
         x = apply_norm(p["ln1"], h, cfg)
         return h + ssm_mod.apply_mamba(p["mamba"], x, cfg), aux
-    x = apply_norm(p["ln1"], h, cfg)
-    a = attn.attention(p["attn"], x, cfg, positions=positions, window=window,
-                       impl=impl)
+    with jax.named_scope("attention"):
+        x = apply_norm(p["ln1"], h, cfg)
+        a = attn.attention(p["attn"], x, cfg, positions=positions,
+                           window=window, impl=impl)
     if cfg.hybrid:
         hd = cfg.resolved_head_dim()
         s = ssm_mod.apply_mamba(p["mamba"], x, cfg,
@@ -94,11 +95,12 @@ def _block_fwd(p, h, cfg: ModelConfig, positions, window, impl=None):
         a = 0.5 * (_chan_norm(a, cfg) * p["mix_a"].astype(a.dtype)
                    + _chan_norm(s, cfg) * p["mix_s"].astype(a.dtype))
     h = h + a
-    x = apply_norm(p["ln2"], h, cfg)
-    if cfg.num_experts:
-        y, aux = moe_mod.apply_moe(p["moe"], x, cfg)
-    else:
-        y = apply_mlp(p["mlp"], x, cfg)
+    with jax.named_scope("mlp"):
+        x = apply_norm(p["ln2"], h, cfg)
+        if cfg.num_experts:
+            y, aux = moe_mod.apply_moe(p["moe"], x, cfg)
+        else:
+            y = apply_mlp(p["mlp"], x, cfg)
     return h + y, aux
 
 
@@ -313,7 +315,8 @@ def _chunked_ce(params, h, labels, cfg: ModelConfig) -> jax.Array:
 def lm_loss(params, batch, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
     if cfg.loss_chunk:
         h, aux = forward_hidden(params, batch, cfg)
-        ce = _chunked_ce(params, h, batch["labels"], cfg)
+        with jax.named_scope("lm_head"):
+            ce = _chunked_ce(params, h, batch["labels"], cfg)
     else:
         logits, aux = forward_lm(params, batch, cfg)
         ce = softmax_cross_entropy(logits, batch["labels"], z_loss=cfg.z_loss)

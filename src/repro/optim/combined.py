@@ -62,9 +62,10 @@ def partitioned(opts: dict, label_fn: Callable) -> Optimizer:
         new_state = {}
         for lab in labels:
             mask = _mask(grads, label_fn, lab)
-            upd, new_state[lab] = opts[lab].update(
-                _masked_tree(grads, mask), state[lab],
-                _masked_tree(params, mask), step)
+            with jax.named_scope(lab):
+                upd, new_state[lab] = opts[lab].update(
+                    _masked_tree(grads, mask), state[lab],
+                    _masked_tree(params, mask), step)
             total = jax.tree.map(
                 lambda acc, u, m: acc + u.astype(jnp.float32) if m else acc,
                 total, upd, mask)
@@ -88,7 +89,8 @@ def nanochat_optimizer(cfg: OptimizerConfig) -> Optimizer:
         return inner
 
     def update(grads, state, params, step):
-        grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
+        with jax.named_scope("clip"):
+            grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
         return inner.update(grads, state, params, step)
 
     return Optimizer(inner.init, update)

@@ -55,7 +55,8 @@ def muon(lr: Union[float, Callable] = 0.02, momentum: float = 0.95,
             g = g.astype(jnp.float32)
             mu = momentum * mu + g
             eff = g + momentum * mu if nesterov else mu
-            o = newton_schulz(eff, ns_steps)
+            with jax.named_scope("newton_schulz"):
+                o = newton_schulz(eff, ns_steps)
             # scale: matrices update at spectral-norm-equalized magnitude
             m, n = o.shape[-2], o.shape[-1]
             scale = jnp.sqrt(jnp.maximum(1.0, m / n))
