@@ -3,6 +3,7 @@ schedules."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.base import OptimizerConfig
 from repro.optim import (adamw, apply_updates, lr_schedule, muon,
@@ -37,6 +38,52 @@ def test_muon_stacked_params():
     single = newton_schulz(G[2])
     np.testing.assert_allclose(np.asarray(O[2]), np.asarray(single),
                                rtol=1e-4, atol=1e-5)
+
+
+def _newton_schulz_transposing(G, steps=5, eps=1e-7):
+    """Straight-line float32 transcription of the transposing form: a tall
+    matrix is swapped wide, normalised, iterated with A = X·Xᵀ and
+    X <- aX + (bA + cA²)·X, and swapped back."""
+    a, b, c = 3.4445, -4.7750, 2.0315
+    X = G.astype(jnp.float32)
+    tall = X.shape[-2] > X.shape[-1]
+    if tall:
+        X = jnp.swapaxes(X, -1, -2)
+    X = X / (jnp.sqrt(jnp.sum(X * X, axis=(-2, -1), keepdims=True)) + eps)
+    for _ in range(steps):
+        A = X @ jnp.swapaxes(X, -1, -2)
+        X = a * X + (b * A + c * (A @ A)) @ X
+    return jnp.swapaxes(X, -1, -2) if tall else X
+
+
+@pytest.mark.parametrize("shape", [(16, 40), (40, 16), (32, 32), (17, 16),
+                                   (3, 24, 16), (2, 3, 16, 24)])
+def test_newton_schulz_matches_transposing_form(shape):
+    """The small-Gram, transpose-free iteration is the transposing one up to
+    float32 accumulation order, and commutes with a transpose."""
+    G = jax.random.normal(jax.random.key(len(shape) * 100 + shape[-1]), shape)
+    O = np.asarray(newton_schulz(G))
+    np.testing.assert_allclose(O, np.asarray(_newton_schulz_transposing(G)),
+                               rtol=0, atol=1e-5)
+    Ot = np.asarray(newton_schulz(jnp.swapaxes(G, -1, -2)))
+    np.testing.assert_allclose(Ot, np.swapaxes(O, -1, -2), rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", [(2, 3, 24, 40), (2, 3, 40, 24)])
+def test_newton_schulz_is_straight_line_matmuls(shape):
+    """Each iteration is three matmuls on the small Gram side: no loop, and
+    no transpose of the iterate."""
+    steps = 5
+    jaxpr = jax.make_jaxpr(lambda g: newton_schulz(g, steps))(
+        jnp.zeros(shape, jnp.float32))
+    prims = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
+    assert not {"transpose", "scan", "while"} & set(prims)
+    assert prims.count("dot_general") == 3 * steps
+    small = min(shape[-2:])
+    for eqn in jaxpr.jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            assert eqn.outvars[0].aval.shape[-2:] in {(small, small),
+                                                     shape[-2:]}
 
 
 def test_sgd_nesterov_math():
