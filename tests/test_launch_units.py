@@ -28,6 +28,19 @@ def test_spec_for_divisibility_fallback():
     assert spec == P("model", None)
 
 
+def test_qwen15_05b_published_keys():
+    """The registry entry runs Qwen1.5-0.5B's published config.json
+    (Qwen2ForCausalLM): sizes, SwiGLU, QKV bias, tied table, rotary
+    theta and RMSNorm eps; full attention (no sliding window)."""
+    c = get_config("qwen1.5-0.5b")
+    assert (c.num_layers, c.d_model, c.num_heads, c.num_kv_heads,
+            c.resolved_head_dim(), c.d_ff, c.vocab_size) == \
+        (24, 1024, 16, 16, 64, 2816, 151936)
+    assert c.mlp_activation == "swiglu" and c.qkv_bias and c.tie_embeddings
+    assert c.rope_theta == 1e6 and c.norm_eps == 1e-6
+    assert c.window == 0 and not c.window_pattern
+
+
 def test_long_context_variant_subquadratic():
     for aid in ("command-r-plus-104b", "mistral-large-123b", "qwen1.5-0.5b"):
         cfg = long_context_variant(get_config(aid))
