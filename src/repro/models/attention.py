@@ -1,8 +1,12 @@
 """Grouped-query attention with rotary embeddings, sliding windows and KV
 caches (full + ring-buffer) — the reference jnp implementation.
 
-Three execution paths, selected per call site:
+Four execution paths, selected per call by ``select_impl`` from what the
+call can observe (backend, shapes, causality, whether the window is static):
 
+* ``_flash``    — the Pallas flash kernel (``kernels/flash_attention``),
+                  forward and backward, score tiles kept in VMEM; causal
+                  self-attention with a static window on a TPU.
 * ``_direct``   — materialized scores; short sequences (train_4k, decode).
 * ``_blocked``  — lax.scan over KV chunks with an online softmax (the pure-jnp
                   mirror of the Pallas flash kernel); long prefill.
@@ -64,6 +68,34 @@ FORCE_IMPL: Optional[str] = None
 DIRECT_MAX_KV = 4096
 BLOCK_Q = 512
 BLOCK_KV = 1024
+# Flash kernel tiles, largest first: the first that divides S is used.  At
+# S=2048 on a v5e, 1024-wide tiles beat 512 (fewer grid steps outweigh the
+# causal waste: 3 of 4 tile pairs computed against 10 of 16); they compile
+# within VMEM for head dims up to 256.
+FLASH_BLOCKS = (1024, 512, 256, 128)
+
+
+def flash_block(S: int) -> int:
+    """The flash tile (query and key block) for length S."""
+    return next(b for b in FLASH_BLOCKS if S % b == 0)
+
+
+def select_impl(*, backend: str, S: int, Sk: int, causal: bool,
+                static_window: bool, window: Optional[int],
+                fp8: bool) -> str:
+    """The attention path for one call.  A pure function of what the call
+    can observe: the flash kernel takes causal self-attention with a static
+    window on a TPU when S is a multiple of a flash block; everything else
+    (and every call on a CPU) keeps the jnp paths."""
+    if (backend == "tpu" and causal and S == Sk and static_window
+            and not fp8 and S % FLASH_BLOCKS[-1] == 0):
+        return "flash"
+    if (static_window and window and window < Sk and Sk > DIRECT_MAX_KV
+            and causal and S == Sk and S % min(BLOCK_Q, S) == 0):
+        return "banded"
+    if Sk > DIRECT_MAX_KV:
+        return "blocked"
+    return "direct"
 
 
 # ---------------------------------------------------------------------------
@@ -133,6 +165,20 @@ def _direct(q, k, v, bias):
         s = s + bias
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+
+
+def _flash(q, k, v, window):
+    """Causal self-attention through the Pallas flash kernel; query i and
+    key j sit at positions i and j.  q: (B,S,KV,G,D), k/v: (B,S,KV,D).
+    Every matmul takes bfloat16 operands and accumulates in float32."""
+    from repro.kernels.flash_attention import flash_attention
+    B, S, KV, G, D = q.shape
+    blk = flash_block(S)
+    qh = q.reshape(B, S, KV * G, D).transpose(0, 2, 1, 3)     # (B,H,S,D)
+    o = flash_attention(qh, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                        causal=True, window=window, bq=blk, bk=blk,
+                        dot_dtype=jnp.bfloat16)
+    return o.transpose(0, 2, 1, 3).reshape(B, S, KV, G, D)
 
 
 def _blocked(q, k, v, q_pos, k_pos, window, causal):
@@ -251,7 +297,9 @@ def attention(p, x, cfg: ModelConfig, *, positions: jax.Array,
               memory: Optional[jax.Array] = None,
               impl: Optional[str] = None) -> jax.Array:
     """Training / prefill attention.  ``memory`` switches to cross-attention
-    (bidirectional over the encoder output)."""
+    (bidirectional over the encoder output).  ``impl`` forces a path
+    ("flash", "direct", "blocked", "banded"); None lets ``select_impl``
+    choose.  Self-attention runs over ``positions`` 0..S-1."""
     B, S = x.shape[:2]
     cross = memory is not None
     xkv = memory if cross else x
@@ -270,16 +318,16 @@ def attention(p, x, cfg: ModelConfig, *, positions: jax.Array,
         w = window  # traced per-layer window (0 already mapped to "huge")
     causal = not cross
     Sk = xkv.shape[1]
-    mode = impl or FORCE_IMPL
-    if mode is None:
-        if (static_window and w and w < Sk and Sk > DIRECT_MAX_KV and causal
-                and S == Sk and S % min(BLOCK_Q, S) == 0):
-            mode = "banded"
-        elif Sk > DIRECT_MAX_KV:
-            mode = "blocked"
-        else:
-            mode = "direct"
-    if mode == "banded":
+    mode = impl or FORCE_IMPL or select_impl(
+        backend=jax.default_backend(), S=S, Sk=Sk, causal=causal,
+        static_window=static_window, window=w, fp8=cfg.fp8_matmul)
+    if mode == "flash":
+        if not (causal and S == Sk and static_window):
+            raise ValueError("impl='flash' takes causal self-attention "
+                             "with a static window")
+        with jax.named_scope("flash_attention"):
+            out = _flash(q, k, v, w)
+    elif mode == "banded":
         out = _banded(q, k, v, w, cfg)
     elif mode == "blocked":
         out = _blocked(q, k, v, q_pos, k_pos, w, causal)

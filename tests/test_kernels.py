@@ -27,7 +27,8 @@ def test_flash_attention_sweep(B, H, KV, S, D, causal, window):
     q = jax.random.normal(ks[0], (B, H, S, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, KV, S, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, KV, S, D), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, window=window)
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          dot_dtype=jnp.float32)
     ref = reference_attention(q, k, v, causal=causal, window=window)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -38,7 +39,7 @@ def test_flash_attention_dtypes(dtype):
     q = jax.random.normal(ks[0], (1, 2, 128, 64)).astype(dtype)
     k = jax.random.normal(ks[1], (1, 2, 128, 64)).astype(dtype)
     v = jax.random.normal(ks[2], (1, 2, 128, 64)).astype(dtype)
-    out = flash_attention(q, k, v)
+    out = flash_attention(q, k, v, dot_dtype=jnp.float32)
     ref = reference_attention(q, k, v)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert out.dtype == dtype
@@ -57,7 +58,8 @@ def test_flash_attention_fp8_matches_oracle(causal, window):
     q = jax.random.normal(ks[0], (1, 4, 256, 64), jnp.float32)
     k = jax.random.normal(ks[1], (1, 2, 256, 64), jnp.float32)
     v = jax.random.normal(ks[2], (1, 2, 256, 64), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, window=window, fp8=True)
+    out = flash_attention(q, k, v, causal=causal, window=window, fp8=True,
+                          dot_dtype=jnp.float32)
     ref = reference_attention_fp8(q, k, v, causal=causal, window=window)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
     exact = reference_attention(q, k, v, causal=causal, window=window)
@@ -69,9 +71,55 @@ def test_flash_attention_mismatched_qk_len():
     q = jax.random.normal(ks[0], (1, 2, 128, 64))
     k = jax.random.normal(ks[1], (1, 2, 256, 64))
     v = jax.random.normal(ks[2], (1, 2, 256, 64))
-    out = flash_attention(q, k, v, causal=False)
+    out = flash_attention(q, k, v, causal=False, dot_dtype=jnp.float32)
     ref = reference_attention(q, k, v, causal=False)
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
+
+
+# bfloat16 keeps 8 significant bits: each rounded operand is off by at
+# most u = 2^-8 relative.  A product of two rounded operands is off by 2u,
+# and the backward chains two rounded matmuls (dP -> dS -> dQ/dK), so 4u of
+# the largest entry bounds every output.  The lower bound (u/8) shows the
+# tiles really were rounded: float32 operands land near 1e-7.
+BF16_U = 2.0 ** -8
+
+
+@pytest.mark.parametrize("dot_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("H,KV,D,window", [
+    (4, 2, 64, None),        # GQA, two query heads per KV head
+    (2, 2, 128, None),
+    (4, 1, 64, 64),          # sliding window, four heads per KV head
+    (2, 2, 128, 96),
+])
+def test_flash_attention_grad_matches_reference(H, KV, D, window, dot_dtype):
+    """O and jax.grad (dQ, dK, dV) of the kernel's custom_vjp against
+    jax.grad of the materialised-scores oracle: causal, S 256, blocks of
+    128 (so the diagonal, skipped and, with a window, partly visible
+    blocks all occur)."""
+    S = 256
+    ks = jax.random.split(jax.random.key(S + H + D), 4)
+    q = jax.random.normal(ks[0], (1, H, S, D), jnp.float32)
+    k = jax.random.normal(ks[1], (1, KV, S, D), jnp.float32)
+    v = jax.random.normal(ks[2], (1, KV, S, D), jnp.float32)
+    do = jax.random.normal(ks[3], (1, H, S, D), jnp.float32)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, window=window, **kw) * do)
+
+    out = flash_attention(q, k, v, window=window, bq=128, bk=128,
+                          dot_dtype=dot_dtype)
+    grads = jax.grad(loss(flash_attention, bq=128, bk=128,
+                          dot_dtype=dot_dtype), argnums=(0, 1, 2))(q, k, v)
+    ref = reference_attention(q, k, v, window=window)
+    ref_grads = jax.grad(loss(reference_attention), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), (out, *grads),
+                          (ref, *ref_grads)):
+        assert a.shape == b.shape and a.dtype == jnp.float32, name
+        rel = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+        if dot_dtype == "float32":
+            assert rel < 1e-5, (name, rel)
+        else:
+            assert BF16_U / 8 < rel < 4 * BF16_U, (name, rel)
 
 
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [

@@ -115,6 +115,95 @@ def test_attention_impl_equivalence(impl):
     assert float(jnp.max(jnp.abs(out - ref))) < 2e-3
 
 
+# Tiny stand-ins for the training cells' attention: nanochat-d20's 128-wide
+# heads, and Qwen1.5-0.5B's 64-wide heads with QKV bias.
+FLASH_CFGS = {
+    "d20-like": dict(d_model=256, num_heads=2, num_kv_heads=2, head_dim=128),
+    "qwen-like": dict(d_model=128, num_heads=2, num_kv_heads=2, head_dim=64,
+                      qkv_bias=True),
+}
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("kind", sorted(FLASH_CFGS))
+def test_flash_attention_matches_direct(kind, window):
+    """attention(impl="flash") against impl="direct": values and gradients
+    with respect to the attention parameters, through jax.checkpoint and a
+    jax.vmap over workers, as core/diloco.py wraps the step.  The flash
+    path rounds every matmul operand to bfloat16 (the direct path on a CPU
+    does not), so both sides agree to 4u of the largest entry, u = 2^-8
+    (see test_kernels.py)."""
+    from repro.models.layers import split_logical
+    cfg = tiny_cfg("dense", **FLASH_CFGS[kind])
+    S, workers = 256, 2
+    p, _ = split_logical(attn_mod.init_attention(jax.random.key(0), cfg))
+    # move the biases off their zero init so their gradients carry weight
+    p = {n: a + 0.1 * jax.random.normal(jax.random.key(i), a.shape)
+         for i, (n, a) in enumerate(sorted(p.items()))}
+    x = jax.random.normal(jax.random.key(1), (workers, 1, S, cfg.d_model))
+    y = jax.random.normal(jax.random.key(2), (1, S, cfg.d_model))
+    positions = jnp.arange(S)
+
+    def run(impl):
+        def loss(p, x):
+            out = attn_mod.attention(p, x, cfg, positions=positions,
+                                     window=window, impl=impl)
+            return jnp.sum(out * y)
+        step = jax.value_and_grad(jax.checkpoint(loss))
+        return jax.jit(jax.vmap(step, in_axes=(None, 0)))(p, x)
+
+    (val, grads), (ref_val, ref_grads) = run("flash"), run("direct")
+    scale = float(jnp.max(jnp.abs(ref_val)))
+    assert float(jnp.max(jnp.abs(val - ref_val))) < 4 * 2.0 ** -8 * scale
+    assert sorted(grads) == sorted(p)
+    for name in grads:
+        g, r = grads[name], ref_grads[name]
+        assert g.shape == (workers,) + p[name].shape
+        rel = float(jnp.max(jnp.abs(g - r)) / jnp.max(jnp.abs(r)))
+        assert rel < 4 * 2.0 ** -8, (name, rel)
+
+
+def _select_before_flash(*, S, Sk, causal, static_window, window):
+    """The path choice as it stood before the flash kernel took TPU calls."""
+    if (static_window and window and window < Sk
+            and Sk > attn_mod.DIRECT_MAX_KV and causal and S == Sk
+            and S % min(attn_mod.BLOCK_Q, S) == 0):
+        return "banded"
+    if Sk > attn_mod.DIRECT_MAX_KV:
+        return "blocked"
+    return "direct"
+
+
+SELECT_CASES = [
+    # (S, Sk, causal, static_window, window, fp8)
+    (2048, 2048, True, True, None, False),   # the training cells
+    (2048, 2048, True, True, 512, False),    # static sliding window
+    (8192, 8192, True, True, 1024, False),   # long, windowed (banded on CPU)
+    (8192, 8192, True, True, None, False),   # long, global (blocked on CPU)
+    (2048, 2048, True, False, None, False),  # traced per-layer window
+    (2048, 1024, False, True, None, False),  # cross-attention
+    (200, 200, True, True, None, False),     # length off the block
+    (2048, 2048, True, True, None, True),    # fp8 matmuls
+]
+
+
+@pytest.mark.parametrize("case", SELECT_CASES)
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_select_impl_flash_only_on_tpu(backend, case):
+    """The choice is a pure function of backend, shapes and window: flash
+    for causal self-attention with a static window and a block-multiple
+    length on a TPU, and on a CPU exactly the choice made before."""
+    S, Sk, causal, static_window, window, fp8 = case
+    got = attn_mod.select_impl(backend=backend, S=S, Sk=Sk, causal=causal,
+                               static_window=static_window, window=window,
+                               fp8=fp8)
+    before = _select_before_flash(S=S, Sk=Sk, causal=causal,
+                                  static_window=static_window, window=window)
+    flash_ok = causal and S == Sk and static_window and not fp8 \
+        and S % 128 == 0
+    assert got == ("flash" if backend == "tpu" and flash_ok else before)
+
+
 def test_banded_swa_equals_direct():
     """The O(S·W) banded prefill must match the O(S²) masked path."""
     cfg = tiny_cfg("dense", window=8)

@@ -1,6 +1,7 @@
 """Compile rehearsal of the main-path Pallas kernels for one described TPU
 v5e chip at nanochat-d20 widths (head_dim 128, 10 KV heads, d_ff 5120,
-vocab 65536).  Nothing runs: Mosaic and XLA:TPU compile for a chip that is
+vocab 65536), and of the training path's flash attention at d20's and
+Qwen1.5-0.5B's head widths.  Nothing runs: Mosaic and XLA:TPU compile for a chip that is
 described, not attached, and refuse what the chip would refuse — tiling
 violations and VMEM overruns that interpret-mode tests cannot see.
 
@@ -94,3 +95,24 @@ def test_fused_adamw_compiles_on_d20_embedding(one_chip):
         p, g, m, v, lr, b1, b2, b1=0.9, b2=0.95, eps=1e-10, wd=0.0,
         interpret=False), one_chip, leaf, leaf, leaf, leaf,
         scalar, scalar, scalar)
+
+
+@pytest.mark.parametrize("H,D", [(10, 128), (16, 64)])   # d20, Qwen1.5-0.5B
+def test_flash_attention_grad_compiles(one_chip, H, D):
+    """jax.grad through the flash kernel's custom_vjp at one training row of
+    2,048 tokens, with the block attention() picks there: the forward, the
+    dK/dV sweep and the dQ sweep each compile through Mosaic."""
+    from repro.kernels.flash_attention import flash_attention
+    from repro.models.attention import flash_block
+    S = 2048
+    blk = flash_block(S)
+
+    def grads(q, k, v, do):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, bq=blk, bk=blk, interpret=False)
+            return jnp.sum(o * do)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = _compile(grads, one_chip, *[((1, H, S, D), jnp.float32)] * 4)
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 3
