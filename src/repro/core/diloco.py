@@ -8,15 +8,10 @@ training loop:
     every H:      average parameter deltas, outer Nesterov SGD, re-broadcast
 
 Workers are encoded as a leading ``K`` dimension on params / optimizer state,
-and the inner step is ``jax.vmap`` of the single-worker step.  That single
-encoding serves both deployments:
-
-* **simulation** (paper reproduction on one CPU device): K workers vmapped
-  on one chip — bit-faithful algorithm, no hardware needed;
-* **multi-pod** (production): the K dim is sharded over the mesh's ``pod``
-  axis — XLA keeps inner steps pod-local (verified: inner-step HLO contains
-  only within-pod collectives) and the outer step's delta exchange becomes
-  the only inter-pod communication.
+and the inner step is ``jax.vmap`` of the single-worker step: K workers
+vmapped on one device, a bit-faithful simulation of the algorithm.  Sharding
+the K dim over a device axis, one worker per chip, is ROADMAP R1; no launcher
+builds that today.
 
 The DDP baseline (``repro.core.ddp``) is the same inner step with K=1 and the
 global batch, synchronizing every step.
@@ -94,7 +89,7 @@ class DiLoCoTrainer:
                                inner_step=state.inner_step + 1),
                 loss, metrics)
 
-    def inner_chunk(self, state: DiLoCoState, batches
+    def inner_chunk(self, state: DiLoCoState, batches, live=None
                     ) -> Tuple[DiLoCoState, jax.Array]:
         """Scan-fused run of T inner steps — the device-speed hot path.
 
@@ -110,33 +105,20 @@ class DiLoCoTrainer:
         bit-exactness; the worker mean is instead taken on the host in a
         fixed order (``dist_trainer._host_mean``) in both loops.
 
+        ``live`` is an optional (K,) bool liveness mask: dead rows' params
+        and optimizer state pass through frozen (a traced argument, so a
+        changing live set never retraces); their losses are still in the
+        (T, K) output and the trainer leaves them out of the recorded
+        mean.  ``None`` is the whole fleet, a constant built inside the
+        scanned body so that XLA folds the merge away (a mask closed over
+        by the scan would be a loop operand it need not fold).
+
         The scan carry holds ONLY what the inner step mutates
         (``worker_params``, ``inner_opt``, ``inner_step``);
         ``global_params`` and the outer-optimizer state are loop-invariant
         closures, so XLA hoists them instead of threading (and on some
         backends copying) them through every iteration.
         """
-        def body(carry, batch):
-            wp, opt, istep = carry
-            st = state._replace(worker_params=wp, inner_opt=opt,
-                                inner_step=istep)
-            st, loss, _ = self.inner_step(st, batch)
-            return (st.worker_params, st.inner_opt, st.inner_step), loss
-
-        carry = (state.worker_params, state.inner_opt, state.inner_step)
-        (wp, opt, istep), losses = jax.lax.scan(body, carry, batches)
-        return (state._replace(worker_params=wp, inner_opt=opt,
-                               inner_step=istep), losses)
-
-    def inner_chunk_live(self, state: DiLoCoState, batches, live
-                         ) -> Tuple[DiLoCoState, jax.Array]:
-        """``inner_chunk`` under a (K,) liveness mask: dead rows' params and
-        optimizer state pass through frozen (``jnp.where`` merge — the mask
-        is a traced argument, so a changing live set never retraces).  The
-        (T, K) losses still cover every row; the trainer masks dead rows
-        out of the recorded mean on the host.  Only dispatched when at
-        least one worker is down — the all-live path keeps using
-        ``inner_chunk``'s unmodified program."""
         rows = outer_opt._mask_rows
 
         def body(carry, batch):
@@ -144,12 +126,10 @@ class DiLoCoTrainer:
             st = state._replace(worker_params=wp, inner_opt=opt,
                                 inner_step=istep)
             st, loss, _ = self.inner_step(st, batch)
-            new_wp = jax.tree.map(
-                lambda n, o: jnp.where(rows(live, n), n, o),
-                st.worker_params, wp)
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(rows(live, n), n, o),
-                st.inner_opt, opt)
+            m = outer_opt.fleet_mask(live, self.cfg.num_workers, True)
+            new_wp, new_opt = jax.tree.map(
+                lambda n, o: jnp.where(rows(m, n), n, o),
+                (st.worker_params, st.inner_opt), (wp, opt))
             return (new_wp, new_opt, st.inner_step), loss
 
         carry = (state.worker_params, state.inner_opt, state.inner_step)
@@ -171,34 +151,13 @@ class DiLoCoTrainer:
                              params)
         return _broadcast(zeros, self.cfg.num_workers)
 
-    def outer_step_ef(self, state: DiLoCoState, residual=None):
+    def outer_step_ef(self, state: DiLoCoState, residual=None,
+                      contrib=None, adopt=None, reset=None):
         """Outer sync through the codec transport with an optional
-        error-feedback residual; returns (new state, new residual)."""
-        with jax.named_scope("outer_step"):
-            delta = jax.tree.map(
-                lambda w, g: (w.astype(jnp.float32)
-                              - g.astype(jnp.float32)[None]),
-                state.worker_params, state.global_params)
-            avg, new_residual = outer_opt.exchange_and_average(
-                delta, self.cfg, self.replicate_fn, residual=residual)
-            new_global, new_outer = outer_opt.outer_update(
-                state.global_params, avg, state.outer, self.cfg)
-            # re-broadcast the synchronized params; inner optimizer state is
-            # kept per-worker across syncs (paper §3 — AdamW/Muon state is
-            # local)
-            new_wp = _broadcast(new_global, self.cfg.num_workers)
-            return state._replace(global_params=new_global,
-                                  worker_params=new_wp,
-                                  outer=new_outer), new_residual
+        error-feedback residual; returns (new state, new residual).
 
-    def outer_step(self, state: DiLoCoState) -> DiLoCoState:
-        return self.outer_step_ef(state)[0]
-
-    # -- quorum outer step + elastic rejoin (fault-tolerant variants) --------
-    def outer_step_quorum(self, state: DiLoCoState, residual,
-                          contrib, adopt, reset):
-        """``outer_step_ef`` under (K,) quorum masks (all traced bools —
-        fixed signature, a changing live set never retraces):
+        The (K,) bool fleet masks of a quorum round (traced, so a changing
+        live set never retraces):
 
         * ``contrib`` — rows whose deltas enter the masked average;
         * ``adopt``   — live rows that take the new anchor (keeps their
@@ -207,9 +166,16 @@ class DiLoCoTrainer:
           optimizer + error-feedback state from zero (AdamW/Muon moments
           init to zeros, so zeroing IS re-initialization);
         * rows in none of the masks (dead workers) pass through frozen.
-        """
+
+        Left None they mean the whole fleet (all contribute and adopt,
+        none resets): constants built here, inside the trace, which XLA
+        folds away (``outer_opt.fleet_mask``)."""
         with jax.named_scope("outer_step"):
+            k = self.cfg.num_workers
             rows = outer_opt._mask_rows
+            contrib = outer_opt.fleet_mask(contrib, k, True)
+            adopt = outer_opt.fleet_mask(adopt, k, True)
+            reset = outer_opt.fleet_mask(reset, k, False)
             delta = jax.tree.map(
                 lambda w, g: (w.astype(jnp.float32)
                               - g.astype(jnp.float32)[None]),
@@ -219,26 +185,28 @@ class DiLoCoTrainer:
                 live=contrib)
             new_global, new_outer = outer_opt.outer_update(
                 state.global_params, avg, state.outer, self.cfg)
+            # adopters take the synchronized params; inner optimizer state
+            # is kept per-worker across syncs (paper §3 — AdamW/Muon state
+            # is local)
             take = jnp.logical_or(adopt, reset)
             new_wp = jax.tree.map(
                 lambda g, o: jnp.where(rows(take, o), g[None], o),
                 new_global, state.worker_params)
-            new_opt = jax.tree.map(
-                lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-                state.inner_opt)
             if new_residual is not None:
                 # non-contributors never shipped, so their EF carry is
                 # unchanged; rejoiners restart with a clean carry
-                new_residual = jax.tree.map(
-                    lambda n, o: jnp.where(
-                        rows(reset, n), jnp.zeros_like(n),
-                        jnp.where(rows(contrib, n), n, o)),
-                    new_residual, residual)
-            return state._replace(global_params=new_global,
-                                  worker_params=new_wp,
-                                  inner_opt=new_opt,
-                                  outer=new_outer), new_residual
+                new_residual = outer_opt.zero_rows(reset, jax.tree.map(
+                    lambda n, o: jnp.where(rows(contrib, n), n, o),
+                    new_residual, residual))
+            return state._replace(
+                global_params=new_global, worker_params=new_wp,
+                inner_opt=outer_opt.zero_rows(reset, state.inner_opt),
+                outer=new_outer), new_residual
 
+    def outer_step(self, state: DiLoCoState) -> DiLoCoState:
+        return self.outer_step_ef(state)[0]
+
+    # -- elastic rejoin -------------------------------------------------------
     def adopt_anchor(self, state: DiLoCoState, residual, reset):
         """Rejoin without a round (quorum skipped): ``reset`` rows adopt
         the CURRENT anchor with zeroed inner-opt/EF state; the anchor and
@@ -247,15 +215,11 @@ class DiLoCoTrainer:
         new_wp = jax.tree.map(
             lambda g, o: jnp.where(rows(reset, o), g[None], o),
             state.global_params, state.worker_params)
-        new_opt = jax.tree.map(
-            lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-            state.inner_opt)
         if residual is not None:
-            residual = jax.tree.map(
-                lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-                residual)
-        return state._replace(worker_params=new_wp,
-                              inner_opt=new_opt), residual
+            residual = outer_opt.zero_rows(reset, residual)
+        return state._replace(
+            worker_params=new_wp,
+            inner_opt=outer_opt.zero_rows(reset, state.inner_opt)), residual
 
     # -- jitted entry points ---------------------------------------------------
     def jit_steps(self):

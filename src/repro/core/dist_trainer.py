@@ -60,6 +60,17 @@ default ``chunked=True`` loop makes that true:
 ``chunked=False`` keeps the original per-step loop — the reference the
 bit-exactness tests (and ``benchmarks/train_bench.py``) compare against.
 
+Fault tolerance
+---------------
+One program per strategy serves fault-free and faulty runs alike.  The
+chunk and the outer steps take the fleet's (K,) masks (live, contributing,
+adopting, rejoining workers) as optional arguments; left out, they default
+to the whole fleet as constants built inside the trace, which XLA folds
+away, so a run without a fault schedule compiles the unmasked programs.
+Under a fault schedule the loop passes the chunk a live mask only while a
+worker is down, and a runner bound to the ``FleetTracker`` passes each
+round's masks to its outer step.
+
 Profiling
 ---------
 The chunked loop and the programs it runs name their parts for JAX's own
@@ -88,8 +99,8 @@ Device scopes (``jax.named_scope``), in the ops' ``op_name`` metadata:
   runs inside that backward;
 * ``inner_opt`` — the optimizer update and its application, with
   ``clip``, ``muon`` (``newton_schulz`` inside it) and ``adamw`` inside;
-* ``outer_step`` — the DiLoCo and Streaming DiLoCo outer-step programs
-  (``outer_step_ef``, ``outer_step_quorum`` and their fragment forms).
+* ``outer_step`` — the DiLoCo and Streaming DiLoCo outer-step programs,
+  ``outer_step_ef`` and ``outer_step_fragment_ef``.
 
 With the profiler off a span is one inactive ``TraceMe`` and a scope is
 compile-time metadata only.
@@ -125,19 +136,7 @@ _span = jax.profiler.TraceAnnotation
 _DONATION_WARNING = "Some donated buffers were not usable"
 
 
-def _bind(strategy: SyncStrategy, engine, params, donate: bool):
-    """strategy.bind with the ``donate`` flag, tolerating pre-existing
-    custom strategies whose bind() lacks the parameter."""
-    import inspect
-    try:
-        has_donate = "donate" in inspect.signature(strategy.bind).parameters
-    except (TypeError, ValueError):
-        has_donate = False
-    return (strategy.bind(engine, params, donate=donate) if has_donate
-            else strategy.bind(engine, params))
-
-
-def _host_mean(row: np.ndarray) -> float:
+def _host_mean(row: np.ndarray, live=None) -> float:
     """Worker-mean of a fetched (K,) loss row, in a FIXED summation order.
 
     Both loops record means of the RAW per-worker losses their jits
@@ -145,19 +144,11 @@ def _host_mean(row: np.ndarray) -> float:
     association per program (eager op vs scan body — a 1-ulp wobble that
     breaks chunked-vs-per-step bit-exactness and, through ``AdaptiveH``'s
     loss window, could even flip a sync decision).  Host IEEE f32 adds in
-    index order are deterministic everywhere.
+    index order are deterministic everywhere.  ``live`` leaves the dead
+    workers' entries out: their frozen params' losses are not part of the
+    fleet's trajectory.
     """
-    acc = row[0]
-    for x in row[1:]:
-        acc = acc + x
-    return float(acc / row.dtype.type(len(row)))
-
-
-def _host_mean_live(row: np.ndarray, live) -> float:
-    """``_host_mean`` over only the live workers' loss entries (dead rows
-    carry frozen params whose losses are not part of the fleet's trajectory).
-    Same fixed index-order summation."""
-    idx = [w for w, l in enumerate(live) if l]
+    idx = [w for w in range(len(row)) if live is None or live[w]]
     if not idx:
         return float("nan")
     acc = row[idx[0]]
@@ -246,24 +237,19 @@ class DistTrainer:
         if resume and not checkpoint_dir:
             raise ValueError("resume=True requires checkpoint_dir")
         eng = self.engine()
-        runner = _bind(self.strategy, eng, state.global_params, donate)
+        runner = self.strategy.bind(eng, state.global_params, donate=donate)
         inner_chunk = jax.jit(eng.inner_chunk,
                               donate_argnums=(0,) if donate else ())
         tracker = None
-        inner_live = None
         if faults is not None and not faults.empty:
             faults.validate(self.cfg.num_workers)
             tracker = FleetTracker(faults, self.cfg.num_workers,
                                    min_quorum=min_quorum)
             if faults.worker_events():
-                # binds the quorum jits; raises for runners that don't
-                # support per-worker faults.  Kill-only schedules skip the
-                # bind so the untouched jit programs stay bit-exact with a
-                # fault-free run (XLA specializes per compiled module).
+                # raises for runners that don't support per-worker faults;
+                # kill-only schedules keep the whole fleet live, so the
+                # runner needs no masks
                 runner.bind_faults(tracker)
-                inner_live = jax.jit(
-                    eng.inner_chunk_live,
-                    donate_argnums=(0,) if donate else ())
         if donate and not consume:
             # the first chunk donates the caller's state buffers; copy once
             # so the object the caller passed in survives the run
@@ -348,28 +334,23 @@ class DistTrainer:
                                        else stack_batches(
                                            [data_fn(s)
                                             for s in range(step, end + 1)]))
+                        if live is not None and all(live):
+                            live = None
                         with _span("trainer.chunk", first_step=step,
                                    steps=T):
-                            if inner_live is not None and not all(live):
-                                # dead rows freeze (params + opt pass
-                                # through); the all-live path keeps the
-                                # original jit program so fault-free
-                                # stretches stay bit-exact with it
-                                state, losses = inner_live(
-                                    state, batches,
-                                    jnp.asarray(live, jnp.bool_))
-                            else:
-                                state, losses = inner_chunk(state, batches)
+                            # a live mask only while a worker is down (its
+                            # rows freeze); otherwise the whole-fleet trace
+                            state, losses = inner_chunk(
+                                state, batches,
+                                *(() if live is None
+                                  else (jnp.asarray(live, jnp.bool_),)))
                         with _span("trainer.fetch"):
                             losses_host = _fetch(losses)  # ONE per chunk
                         with _span("trainer.sync"):
                             for i in range(T):
                                 s = step + i
-                                loss_mean = (
-                                    _host_mean(losses_host[i])
-                                    if live is None or all(live)
-                                    else _host_mean_live(losses_host[i],
-                                                         live))
+                                loss_mean = _host_mean(losses_host[i],
+                                                       live)
                                 if s % record_every == 0:
                                     history["step"].append(s)
                                     history["loss"].append(loss_mean)
@@ -467,7 +448,7 @@ class DistTrainer:
         donate=False — the pre-chunking loop never donated, and an
         eval_fn here may retain references into the live state."""
         eng = self.engine()
-        runner = _bind(self.strategy, eng, state.global_params, False)
+        runner = self.strategy.bind(eng, state.global_params, donate=False)
         inner_jit = jax.jit(eng.inner_step)
         history: Dict[str, list] = {"step": [], "loss": [], "sync_steps": [],
                                     "frag_syncs": [], "evals": []}

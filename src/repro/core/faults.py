@@ -16,8 +16,8 @@ failure a first-class, bit-exactly replayable event:
 * ``FleetTracker`` — the host-side state machine ``DistTrainer.run`` and
   the sync runners consult: per-worker liveness, pending rejoins, the
   per-round contribution/adoption/reset masks (the ``(K,)`` arrays the
-  quorum outer-sync jits take — fixed signatures, a changing live-set
-  never retraces), the ``min_quorum`` skip rule, and the one-retry
+  outer steps take as traced arguments — a changing live set never
+  retraces), the ``min_quorum`` skip rule, and the one-retry
   accounting for dropped payloads.
 * ``SimulatedCrash`` — raised by the trainer after a ``kill`` event's
   step completes (and after any due checkpoint is written), so the

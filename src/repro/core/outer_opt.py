@@ -80,37 +80,60 @@ def _tree_dot(a, b) -> jax.Array:
 
 
 def _mask_rows(mask: jax.Array, ref: jax.Array) -> jax.Array:
-    """(K,) mask broadcast against a (K, ...) leaf — the fixed-signature
-    quorum jits reshape rather than index so the live set never retraces."""
+    """(K,) mask broadcast against a (K, ...) leaf — reshaped rather than
+    indexed, so a traced mask keeps one signature for every live set."""
     return mask.reshape((-1,) + (1,) * (ref.ndim - 1))
+
+
+def fleet_mask(mask: Optional[jax.Array], k: int, fill: bool) -> jax.Array:
+    """``mask``, or the (K,) constant ``fill`` when it is None.
+
+    The default must be built inside the traced function: there it is a
+    compile-time constant, so XLA folds every ``where`` over it away and a
+    division by the masked count becomes the same multiply by ``1/K`` that
+    ``jnp.mean`` lowers to — the whole-fleet program is the unmasked one.
+    An array made outside the trace arrives as an argument, which XLA
+    cannot fold; one closed over by a scan is a loop operand, which XLA
+    need not fold."""
+    return jnp.full((k,), fill) if mask is None else mask
+
+
+def zero_rows(mask: jax.Array, tree) -> Any:
+    """``tree`` with the (K, ...) rows that ``mask`` selects set to zero.
+    An empty leaf (an optimizer's placeholder) is returned as itself:
+    ``jnp.where`` would hand back a fresh empty array, and the program
+    would drop that leaf's argument and output."""
+    return jax.tree.map(
+        lambda x: jnp.where(_mask_rows(mask, x), jnp.zeros_like(x), x)
+        if x.size else x, tree)
+
+
+def masked_mean(mask: jax.Array, x: jax.Array) -> jax.Array:
+    """Mean over the rows of ``x`` that ``mask`` selects (at least one row
+    counted), in the one form that folds to ``jnp.mean(x, axis=0)`` under
+    the all-ones constant mask: a ``where`` then a sum, never a dot with
+    the mask."""
+    n = jnp.maximum(jnp.sum(mask.astype(jnp.float32)), 1.0)
+    return jnp.sum(jnp.where(_mask_rows(mask, x), x, 0.0), axis=0) / n
 
 
 def _average(delta, cfg: DiLoCoConfig, live: Optional[jax.Array] = None
              ) -> Any:
     """Decoded f32 (K, ...) stacked deltas -> averaged delta pytree.
 
-    ``live`` is an optional (K,) bool contribution mask for quorum rounds:
+    ``live`` is the (K,) bool contribution mask of a quorum round:
     masked-out rows are excluded from the mean (and get -inf drift-aware
-    logits).  ``live=None`` keeps the original all-workers expressions
-    verbatim — the no-fault path stays bit-exact.
+    logits).  ``live=None`` is the whole fleet (``fleet_mask``).
     """
+    k = jax.tree.leaves(delta)[0].shape[0]
+    live = fleet_mask(live, k, True)
+    mean = jax.tree.map(lambda d: masked_mean(live, d), delta)
     if not cfg.drift_aware:
-        if live is None:
-            return jax.tree.map(lambda d: jnp.mean(d, axis=0), delta)
-        n = jnp.maximum(jnp.sum(live.astype(jnp.float32)), 1.0)
-        return jax.tree.map(
-            lambda d: jnp.sum(jnp.where(_mask_rows(live, d), d, 0.0),
-                              axis=0) / n, delta)
+        return mean
 
     # drift-aware: weight workers by cosine(Δ_i, Δ̄), τ = 4
-    k = jax.tree.leaves(delta)[0].shape[0]
-    if live is None:
-        mean = jax.tree.map(lambda d: jnp.mean(d, axis=0), delta)
-    else:
-        delta = jax.tree.map(
-            lambda d: jnp.where(_mask_rows(live, d), d, 0.0), delta)
-        n = jnp.maximum(jnp.sum(live.astype(jnp.float32)), 1.0)
-        mean = jax.tree.map(lambda d: jnp.sum(d, axis=0) / n, delta)
+    delta = jax.tree.map(
+        lambda d: jnp.where(_mask_rows(live, d), d, 0.0), delta)
     mean_norm = jnp.sqrt(_tree_dot(mean, mean)) + 1e-12
 
     def cos_i(i):
@@ -119,10 +142,7 @@ def _average(delta, cfg: DiLoCoConfig, live: Optional[jax.Array] = None
         return _tree_dot(di, mean) / (ni * mean_norm)
 
     cos = jnp.stack([cos_i(i) for i in range(k)])
-    logits = 4.0 * cos
-    if live is not None:
-        logits = jnp.where(live, logits, -jnp.inf)
-    w = jax.nn.softmax(logits)                          # (K,)
+    w = jax.nn.softmax(jnp.where(live, 4.0 * cos, -jnp.inf))   # (K,)
     return jax.tree.map(
         lambda d: jnp.tensordot(w, d.astype(jnp.float32), axes=(0, 0)), delta)
 

@@ -76,54 +76,26 @@ class StreamingDiLoCoTrainer(DiLoCoTrainer):
         """Steps between fragment syncs (every fragment syncs each H)."""
         return max(self.cfg.h_inner_steps // self.num_fragments, 1)
 
-    def outer_step_fragment_ef(self, state: DiLoCoState, mask, residual=None):
+    def outer_step_fragment_ef(self, state: DiLoCoState, mask, residual=None,
+                               contrib=None, adopt=None, reset=None):
         """One fragment's outer sync through the codec transport.  The
         error-feedback residual is masked on the way in and merged on the
         way out, so each element's carry only ever reflects its own
-        fragment's quantization error.  Returns (state, new residual)."""
-        with jax.named_scope("outer_step"):
-            delta = jax.tree.map(
-                lambda w, g, m: (w.astype(jnp.float32)
-                                 - g.astype(jnp.float32)[None]) * m[None],
-                state.worker_params, state.global_params, mask)
-            res_in = residual if residual is None else jax.tree.map(
-                lambda r, m: r * m[None], residual, mask)
-            avg, new_res = outer_opt.exchange_and_average(
-                delta, self.cfg, self.replicate_fn, residual=res_in,
-                kind="fragment")
-            new_global, new_outer = outer_opt.outer_update(
-                state.global_params, avg, state.outer, self.cfg)
-            # merge: fragment slots take the synced value, others keep global
-            new_global = jax.tree.map(
-                lambda ng, g, m: jnp.where(m, ng, g),
-                new_global, state.global_params, mask)
-            # workers: fragment slots reset to the synced value, others
-            # diverge on
-            new_wp = jax.tree.map(
-                lambda w, ng, m: jnp.where(m[None], ng[None].astype(w.dtype),
-                                           w),
-                state.worker_params, new_global, mask)
-            if residual is not None:
-                new_res = jax.tree.map(
-                    lambda nr, r, m: jnp.where(m[None], nr, r), new_res,
-                    residual, mask)
-            return state._replace(global_params=new_global,
-                                  worker_params=new_wp,
-                                  outer=new_outer), new_res
+        fragment's quantization error.  Returns (state, new residual).
 
-    def outer_step_fragment(self, state: DiLoCoState, mask) -> DiLoCoState:
-        return self.outer_step_fragment_ef(state, mask)[0]
-
-    def outer_step_fragment_quorum(self, state: DiLoCoState, mask, residual,
-                                   contrib, adopt, reset):
-        """``outer_step_fragment_ef`` under (K,) quorum masks (semantics as
-        ``DiLoCoTrainer.outer_step_quorum``): ``contrib`` rows enter the
+        The (K,) fleet masks mean what they mean in
+        ``DiLoCoTrainer.outer_step_ef``: ``contrib`` rows enter the
         fragment's masked average, ``adopt`` rows take the synced fragment
         slots, ``reset`` rows (rejoiners) take the FULL new global — every
         fragment, regardless of the round's fragment mask — with zeroed
-        inner-opt/EF state, and dead rows pass through frozen."""
+        inner-opt/EF state, and dead rows pass through frozen.  None is
+        the whole fleet, folded away at compile time."""
         with jax.named_scope("outer_step"):
+            k = self.cfg.num_workers
             rows = outer_opt._mask_rows
+            contrib = outer_opt.fleet_mask(contrib, k, True)
+            adopt = outer_opt.fleet_mask(adopt, k, True)
+            reset = outer_opt.fleet_mask(reset, k, False)
             delta = jax.tree.map(
                 lambda w, g, m: (w.astype(jnp.float32)
                                  - g.astype(jnp.float32)[None]) * m[None],
@@ -135,33 +107,31 @@ class StreamingDiLoCoTrainer(DiLoCoTrainer):
                 kind="fragment", live=contrib)
             new_global, new_outer = outer_opt.outer_update(
                 state.global_params, avg, state.outer, self.cfg)
+            # merge: fragment slots take the synced value, others keep global
             new_global = jax.tree.map(
                 lambda ng, g, m: jnp.where(m, ng, g),
                 new_global, state.global_params, mask)
+            # adopters: fragment slots reset to the synced value, others
+            # diverge on; rejoiners take every slot
             new_wp = jax.tree.map(
                 lambda w, ng, m: jnp.where(
-                    jnp.logical_and(rows(adopt, w), m[None]),
+                    jnp.logical_or(
+                        jnp.logical_and(rows(adopt, w), m[None]),
+                        rows(reset, w)),
                     ng[None].astype(w.dtype), w),
                 state.worker_params, new_global, mask)
-            new_wp = jax.tree.map(
-                lambda w, ng: jnp.where(rows(reset, w),
-                                        ng[None].astype(w.dtype), w),
-                new_wp, new_global)
-            new_opt = jax.tree.map(
-                lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-                state.inner_opt)
             if residual is not None:
-                new_res = jax.tree.map(
+                new_res = outer_opt.zero_rows(reset, jax.tree.map(
                     lambda nr, r, m: jnp.where(
                         jnp.logical_and(rows(contrib, r), m[None]), nr, r),
-                    new_res, residual, mask)
-                new_res = jax.tree.map(
-                    lambda r: jnp.where(rows(reset, r), jnp.zeros_like(r), r),
-                    new_res)
-            return state._replace(global_params=new_global,
-                                  worker_params=new_wp,
-                                  inner_opt=new_opt,
-                                  outer=new_outer), new_res
+                    new_res, residual, mask))
+            return state._replace(
+                global_params=new_global, worker_params=new_wp,
+                inner_opt=outer_opt.zero_rows(reset, state.inner_opt),
+                outer=new_outer), new_res
+
+    def outer_step_fragment(self, state: DiLoCoState, mask) -> DiLoCoState:
+        return self.outer_step_fragment_ef(state, mask)[0]
 
     def bytes_per_fragment_sync(self, params, mask) -> int:
         from repro.core.transport import wire_width

@@ -197,6 +197,15 @@ def _jit_rejoin_drift():
     return jax.jit(impl)
 
 
+def _masks(info) -> Tuple[jax.Array, ...]:
+    """The (contrib, adopt, reset) arguments of a quorum round, or none at
+    all without one, so the programs trace their whole-fleet defaults."""
+    if info is None:
+        return ()
+    return tuple(jnp.asarray(m) for m in (info.contrib, info.adopt,
+                                          info.reset))
+
+
 def _rejoin_drift_records(state, reset, live, step: int) -> Records:
     """``core.drift`` metrics for each rejoiner, recorded as
     ``("rejoin_drift", (step, worker, delta_norm, cos_to_live_mean))``."""
@@ -252,6 +261,7 @@ class SyncRunner:
     # worker-level fault schedules for runners that do not.  Run-level
     # ``kill`` events (the crash/resume anchor) need no runner support.
     supports_faults = False
+    _tracker = None
 
     def bind_faults(self, tracker) -> None:
         raise ValueError(
@@ -260,6 +270,27 @@ class SyncRunner:
             "fault-aware strategies (diloco / ddp_compressed / streaming "
             "/ pipelined / gossip), or restrict the schedule to run-level "
             "kill/slow events")
+
+    def _round_masks(self, state, step: int):
+        """The bound tracker's ``RoundInfo`` for the round at ``step`` and
+        its records, rejoin drift included; ``(None, [])`` with no tracker
+        bound, where every program runs with its whole-fleet defaults."""
+        if self._tracker is None:
+            return None, []
+        info = self._tracker.round_masks(step)
+        records = list(info.records)
+        if any(info.reset):
+            records += _rejoin_drift_records(state, info.reset, info.live,
+                                             step)
+        return info, records
+
+    def _adopt_skipped(self, state, info):
+        """A round below quorum: no exchange, but rejoiners still adopt
+        the current anchor (``engine.adopt_anchor``)."""
+        if any(info.reset):
+            state, self.residual = self._adopt(state, self.residual,
+                                               jnp.asarray(info.reset))
+        return state
 
     # -- crash-consistent checkpointing --------------------------------------
     def checkpoint_extras(self) -> Optional[Tuple[Any, Dict]]:
@@ -406,39 +437,21 @@ class _DiLoCoRunner(SyncRunner):
         self.since = 0
         self.residual = engine.init_residual(params)
         self._donate = donate
-        self._tracker = None
         self._outer = jax.jit(engine.outer_step_ef,
                               donate_argnums=(0, 1) if donate else ())
 
     def bind_faults(self, tracker):
         self._tracker = tracker
-        d = (0, 1) if self._donate else ()
-        self._quorum = jax.jit(self.engine.outer_step_quorum,
-                               donate_argnums=d)
-        self._adopt = jax.jit(self.engine.adopt_anchor, donate_argnums=d)
+        self._adopt = jax.jit(self.engine.adopt_anchor,
+                              donate_argnums=(0, 1) if self._donate else ())
 
     def _sync(self, state, step):
-        if self._tracker is None:
-            # no fault schedule bound: the original jitted program,
-            # untouched — the no-fault path stays bit-exact
-            state, self.residual = self._outer(state, self.residual)
-            return state, [("sync_steps", step)]
-        info = self._tracker.round_masks(step)
-        records = list(info.records)
-        if any(info.reset):
-            records += _rejoin_drift_records(state, info.reset, info.live,
-                                             step)
-        reset = jnp.asarray(info.reset)
-        if info.skip:
-            if any(info.reset):
-                state, self.residual = self._adopt(state, self.residual,
-                                                   reset)
-            return state, records
-        state, self.residual = self._quorum(
-            state, self.residual, jnp.asarray(info.contrib),
-            jnp.asarray(info.adopt), reset)
-        records.append(("sync_steps", step))
-        return state, records
+        info, records = self._round_masks(state, step)
+        if info is not None and info.skip:
+            return self._adopt_skipped(state, info), records
+        state, self.residual = self._outer(state, self.residual,
+                                           *_masks(info))
+        return state, records + [("sync_steps", step)]
 
     def after_step(self, state, step, loss):
         self.since += 1
@@ -516,42 +529,25 @@ class _StreamingRunner(SyncRunner):
         self.period = engine.fragment_schedule()
         self.residual = engine.init_residual(params)
         self._donate = donate
-        self._tracker = None
         # donate state + residual (arg 1 is the reused fragment mask)
         self._frag = jax.jit(engine.outer_step_fragment_ef,
                              donate_argnums=(0, 2) if donate else ())
 
     def bind_faults(self, tracker):
         self._tracker = tracker
-        self._fragq = jax.jit(self.engine.outer_step_fragment_quorum,
-                              donate_argnums=(0, 2) if self._donate else ())
         self._adopt = jax.jit(self.engine.adopt_anchor,
                               donate_argnums=(0, 1) if self._donate else ())
 
     def after_step(self, state, step, loss):
-        if (step + 1) % self.period == 0:
-            f = ((step + 1) // self.period - 1) % self.F
-            if self._tracker is None:
-                state, self.residual = self._frag(state, self.masks[f],
-                                                  self.residual)
-                return state, [("frag_syncs", (step, f))]
-            info = self._tracker.round_masks(step)
-            records = list(info.records)
-            if any(info.reset):
-                records += _rejoin_drift_records(state, info.reset,
-                                                 info.live, step)
-            reset = jnp.asarray(info.reset)
-            if info.skip:
-                if any(info.reset):
-                    state, self.residual = self._adopt(state, self.residual,
-                                                       reset)
-                return state, records
-            state, self.residual = self._fragq(
-                state, self.masks[f], self.residual,
-                jnp.asarray(info.contrib), jnp.asarray(info.adopt), reset)
-            records.append(("frag_syncs", (step, f)))
-            return state, records
-        return state, []
+        if (step + 1) % self.period:
+            return state, []
+        f = ((step + 1) // self.period - 1) % self.F
+        info, records = self._round_masks(state, step)
+        if info is not None and info.skip:
+            return self._adopt_skipped(state, info), records
+        state, self.residual = self._frag(state, self.masks[f],
+                                          self.residual, *_masks(info))
+        return state, records + [("frag_syncs", (step, f))]
 
     def checkpoint_extras(self):
         # the fragment slot is a pure function of the step index and
@@ -784,7 +780,6 @@ class _PipelinedRunner(SyncRunner):
         self.pending = None   # (snapshot, fragment, RoundInfo|None) in flight
         self.pending_apply = -1
         self._donate = donate
-        self._tracker = None
         self._apply = jax.jit(self._apply_impl, static_argnames=("frag",),
                               donate_argnums=(0, 2) if donate else ())
         self._outer = jax.jit(engine.outer_step_ef,
@@ -792,23 +787,23 @@ class _PipelinedRunner(SyncRunner):
 
     def bind_faults(self, tracker):
         self._tracker = tracker
-        d = (0, 2) if self._donate else ()
-        self._applyq = jax.jit(self._apply_quorum_impl,
-                               static_argnames=("frag",), donate_argnums=d)
         self._adopt = jax.jit(self.engine.adopt_anchor,
                               donate_argnums=(0, 1) if self._donate else ())
-        self._quorum = jax.jit(self.engine.outer_step_quorum,
-                               donate_argnums=(0, 1) if self._donate else ())
 
-    def _apply_quorum_impl(self, state, snap, residual, contrib, adopt,
-                           reset, *, frag: int):
-        """``_apply_impl`` under quorum masks: ``contrib`` rows enter the
-        fragment's masked average, ``adopt`` rows take the synced fragment
-        slots with in-flight carry-forward, ``reset`` rows (rejoiners)
-        land on the FULL new global with zeroed inner-opt/EF state, dead
-        rows pass through frozen."""
+    def _apply_impl(self, state, snap, residual, contrib=None, adopt=None,
+                    reset=None, *, frag: int):
+        """Land fragment ``frag``'s outer update, computed from the
+        snapshot ``snap``.  Under (K,) quorum masks ``contrib`` rows enter
+        the fragment's masked average, ``adopt`` rows take the synced
+        fragment slots with in-flight carry-forward, ``reset`` rows
+        (rejoiners) land on the FULL new global with zeroed inner-opt/EF
+        state, and dead rows pass through frozen.  None is the whole
+        fleet, folded away at compile time (``outer_opt.fleet_mask``)."""
         cfg = self.engine.cfg
         rows = outer_opt._mask_rows
+        contrib = outer_opt.fleet_mask(contrib, cfg.num_workers, True)
+        adopt = outer_opt.fleet_mask(adopt, cfg.num_workers, True)
+        reset = outer_opt.fleet_mask(reset, cfg.num_workers, False)
         mask = self.masks[frag]
         delta = jax.tree.map(
             lambda s, g, m: (s.astype(jnp.float32)
@@ -824,102 +819,48 @@ class _PipelinedRunner(SyncRunner):
         new_global = jax.tree.map(
             lambda ng, g, m: jnp.where(m, ng, g),
             new_global, state.global_params, mask)
+        # adopters' fragment slots: synced base + progress made while in
+        # flight; their other slots untouched; rejoiners take every slot
         new_wp = jax.tree.map(
             lambda w, s, ng, m: jnp.where(
-                jnp.logical_and(rows(adopt, w), m[None]),
-                (ng.astype(jnp.float32)[None]
-                 + (w.astype(jnp.float32) - s.astype(jnp.float32))
-                 ).astype(w.dtype),
-                w),
+                rows(reset, w), ng[None].astype(w.dtype),
+                jnp.where(
+                    jnp.logical_and(rows(adopt, w), m[None]),
+                    (ng.astype(jnp.float32)[None]
+                     + (w.astype(jnp.float32) - s.astype(jnp.float32))
+                     ).astype(w.dtype),
+                    w)),
             state.worker_params, snap, new_global, mask)
-        new_wp = jax.tree.map(
-            lambda w, ng: jnp.where(rows(reset, w),
-                                    ng[None].astype(w.dtype), w),
-            new_wp, new_global)
-        new_opt = jax.tree.map(
-            lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-            state.inner_opt)
         if residual is not None:
-            new_res = jax.tree.map(
+            new_res = outer_opt.zero_rows(reset, jax.tree.map(
                 lambda nr, r, m: jnp.where(
                     jnp.logical_and(rows(contrib, r), m[None]), nr, r),
-                new_res, residual, mask)
-            new_res = jax.tree.map(
-                lambda r: jnp.where(rows(reset, r), jnp.zeros_like(r), r),
-                new_res)
-        return state._replace(global_params=new_global,
-                              worker_params=new_wp, inner_opt=new_opt,
-                              outer=new_outer), new_res
-
-    def _apply_impl(self, state, snap, residual, *, frag: int):
-        cfg = self.engine.cfg
-        mask = self.masks[frag]
-        delta = jax.tree.map(
-            lambda s, g, m: (s.astype(jnp.float32)
-                             - g.astype(jnp.float32)[None]) * m[None],
-            snap, state.global_params, mask)
-        res_in = residual if residual is None else jax.tree.map(
-            lambda r, m: r * m[None], residual, mask)
-        avg, new_res = outer_opt.exchange_and_average(
-            delta, cfg, self.engine.replicate_fn, residual=res_in,
-            kind="fragment", fragment=frag)
-        new_global, new_outer = outer_opt.outer_update(
-            state.global_params, avg, state.outer, cfg)
-        new_global = jax.tree.map(
-            lambda ng, g, m: jnp.where(m, ng, g),
-            new_global, state.global_params, mask)
-        # fragment slots: synced base + progress made while in flight;
-        # other slots untouched
-        new_wp = jax.tree.map(
-            lambda w, s, ng, m: jnp.where(
-                m[None],
-                (ng.astype(jnp.float32)[None]
-                 + (w.astype(jnp.float32) - s.astype(jnp.float32))
-                 ).astype(w.dtype),
-                w),
-            state.worker_params, snap, new_global, mask)
-        if residual is not None:
-            new_res = jax.tree.map(
-                lambda nr, r, m: jnp.where(m[None], nr, r), new_res,
-                residual, mask)
-        return state._replace(global_params=new_global,
-                              worker_params=new_wp, outer=new_outer), new_res
+                new_res, residual, mask))
+        return state._replace(
+            global_params=new_global, worker_params=new_wp,
+            inner_opt=outer_opt.zero_rows(reset, state.inner_opt),
+            outer=new_outer), new_res
 
     def _apply_pending(self, state, step) -> Tuple[Any, Records]:
         snap, frag, info = self.pending
         self.pending = None
-        if info is None:
-            state, self.residual = self._apply(state, snap, self.residual,
-                                               frag=frag)
-            return state, [("frag_syncs", (step, frag))]
-        records: Records = []
-        if info.skip:
-            if any(info.reset):
-                state, self.residual = self._adopt(
-                    state, self.residual, jnp.asarray(info.reset))
-            return state, records
-        # a worker that crashed while the snapshot was in flight must not
-        # adopt the landing update: intersect with the tracker's live set
-        adopt_now = tuple(a and l for a, l in
-                          zip(info.adopt, self._tracker.live))
-        state, self.residual = self._applyq(
-            state, snap, self.residual, jnp.asarray(info.contrib),
-            jnp.asarray(adopt_now), jnp.asarray(info.reset), frag=frag)
-        records.append(("frag_syncs", (step, frag)))
-        return state, records
+        if info is not None:
+            if info.skip:
+                return self._adopt_skipped(state, info), []
+            # a worker that crashed while the snapshot was in flight must
+            # not adopt the landing update: intersect with the live set
+            info = dataclasses.replace(info, adopt=tuple(
+                a and l for a, l in zip(info.adopt, self._tracker.live)))
+        state, self.residual = self._apply(state, snap, self.residual,
+                                           *_masks(info), frag=frag)
+        return state, [("frag_syncs", (step, frag))]
 
     def after_step(self, state, step, loss):
         records: Records = []
         if (step + 1) % self.h == 0:
-            info = None
-            if self._tracker is not None:
-                # masks captured WITH the snapshot: the deltas in flight
-                # are the capture-time live set's
-                info = self._tracker.round_masks(step)
-                records += list(info.records)
-                if any(info.reset):
-                    records += _rejoin_drift_records(state, info.reset,
-                                                     info.live, step)
+            # masks captured WITH the snapshot: the deltas in flight are
+            # the capture-time live set's
+            info, records = self._round_masks(state, step)
             # copy, not alias: the chunked loop (and the donated apply)
             # consume the state's buffers while this snapshot is in flight
             self.pending = (jax.tree.map(jnp.copy, state.worker_params),
@@ -943,17 +884,14 @@ class _PipelinedRunner(SyncRunner):
             state, recs = self._apply_pending(state, num_steps - 1)
             records += recs
         if num_steps % self.h:        # trailing partial round: full sync
-            if self._tracker is None:
-                state, self.residual = self._outer(state, self.residual)
-                records.append(("sync_steps", num_steps - 1))
-            else:
+            info = None
+            if self._tracker is not None:
                 info = self._tracker.round_masks(num_steps - 1)
                 records += list(info.records)
-                if not info.skip:
-                    state, self.residual = self._quorum(
-                        state, self.residual, jnp.asarray(info.contrib),
-                        jnp.asarray(info.adopt), jnp.asarray(info.reset))
-                    records.append(("sync_steps", num_steps - 1))
+            if info is None or not info.skip:
+                state, self.residual = self._outer(state, self.residual,
+                                                   *_masks(info))
+                records.append(("sync_steps", num_steps - 1))
         return state, records
 
     def checkpoint_extras(self):
@@ -1076,24 +1014,59 @@ def _gossip_outer_rows(cfg, state, anchors, v, avg):
     return new_anchors, ostate.v
 
 
-def _gossip_new_state(state, new_anchors):
-    """Worker params land on their updated anchors; ``global_params``
-    tracks the anchor mean (the fleet consensus estimate) so eval /
-    checkpoint consumers keep working; ``state.outer`` only counts."""
-    new_wp = jax.tree.map(lambda a, w: a.astype(w.dtype),
-                          new_anchors, state.worker_params)
+def _gossip_land(state, anchors, v, residual, take, adopt, reset):
+    """Where a gossip round (or a skipped round's rejoin) leaves the fleet.
+
+    ``reset`` rows (rejoiners) adopt the ``adopt`` rows' anchor consensus
+    with zeroed outer momentum, inner-opt and EF state; ``take`` and
+    ``reset`` rows' params land on their anchors; ``global_params`` tracks
+    the live fleet's anchor mean (dead anchors are stale), the consensus
+    estimate eval / checkpoint consumers read.  Every mean is
+    ``outer_opt.masked_mean``, which the whole-fleet masks fold to
+    ``jnp.mean``.  Returns (state, anchors, v, residual)."""
+    rows = outer_opt._mask_rows
+    anchors = jax.tree.map(
+        lambda a: jnp.where(
+            rows(reset, a),
+            outer_opt.masked_mean(adopt, a.astype(jnp.float32))[None]
+            .astype(a.dtype), a),
+        anchors)
+    take = jnp.logical_or(take, reset)
+    new_wp = jax.tree.map(
+        lambda a, w: jnp.where(rows(take, w), a.astype(w.dtype), w),
+        anchors, state.worker_params)
+    live = jnp.logical_or(adopt, reset)
     new_global = jax.tree.map(
-        lambda a, g: jnp.mean(a.astype(jnp.float32), axis=0).astype(g.dtype),
-        new_anchors, state.global_params)
-    return state._replace(
+        lambda a, g: outer_opt.masked_mean(
+            live, a.astype(jnp.float32)).astype(g.dtype),
+        anchors, state.global_params)
+    if residual is not None:
+        residual = outer_opt.zero_rows(reset, residual)
+    return (state._replace(
         global_params=new_global, worker_params=new_wp,
-        outer=outer_opt.OuterState(state.outer.v, state.outer.t + 1))
+        inner_opt=outer_opt.zero_rows(reset, state.inner_opt)),
+        anchors, outer_opt.zero_rows(reset, v), residual)
 
 
 def _gossip_pair_impl(cfg, replicate_fn, state, anchors, v, residual,
-                      peer_idx):
+                      peer_idx, active=None, adopt=None, reset=None):
     """One synchronized gossip round: encode per-worker deltas, ship ONE
     peer row each, pair-average, per-row outer update.
+
+    The (K,) fleet masks of a quorum round (traced, so a changing live set
+    never retraces):
+
+    * ``active`` — contributors, pair-matched among themselves (a solo
+      leftover self-pairs: its pair mean is the identity, a solo outer
+      step);
+    * ``adopt``  — live veterans, whose post-round anchor mean is the
+      consensus estimate a rejoiner adopts;
+    * ``reset``  — rejoiners: anchors/params := consensus, outer momentum,
+      inner-opt and EF state := 0;
+    * rows in none of the masks (dead workers) pass through frozen.
+
+    None is the whole fleet, folded away at compile time
+    (``outer_opt.fleet_mask``).
 
     Module-level on purpose: ``GossipSync`` and the fully-synchronous
     ``AsyncGossipSync`` specialization jit THIS SAME function, so bitwise
@@ -1101,6 +1074,11 @@ def _gossip_pair_impl(cfg, replicate_fn, state, anchors, v, residual,
     compiler accident — XLA:CPU contracts mul+add chains to FMAs per
     module at the LLVM level, below HLO, so even ``optimization_barrier``
     cannot pin cross-module rounding."""
+    k = cfg.num_workers
+    rows = outer_opt._mask_rows
+    active = outer_opt.fleet_mask(active, k, True)
+    adopt = outer_opt.fleet_mask(adopt, k, True)
+    reset = outer_opt.fleet_mask(reset, k, False)
     transport = outer_opt.make_transport(cfg, replicate_fn)
     delta = jax.tree.map(
         lambda w, a: w.astype(jnp.float32) - a.astype(jnp.float32),
@@ -1122,8 +1100,19 @@ def _gossip_pair_impl(cfg, replicate_fn, state, anchors, v, residual,
 
     base, v_mix = pair_mean(anchors), pair_mean(v)
     avg = jax.tree.map(lambda a, b: a * 0.5 + b * 0.5, dq, peer_dq)
-    new_anchors, new_v = _gossip_outer_rows(cfg, state, base, v_mix, avg)
-    return _gossip_new_state(state, new_anchors), new_anchors, new_v, new_res
+    cand_anchors, cand_v = _gossip_outer_rows(cfg, state, base, v_mix, avg)
+
+    def merge(n, o):
+        return jnp.where(rows(active, n), n, o)
+
+    new_anchors = jax.tree.map(merge, cand_anchors, anchors)
+    new_v = jax.tree.map(merge, cand_v, v)
+    if new_res is not None:
+        new_res = jax.tree.map(merge, new_res, residual)
+    new_state, new_anchors, new_v, new_res = _gossip_land(
+        state, new_anchors, new_v, new_res, active, adopt, reset)
+    return (new_state._replace(outer=outer_opt.OuterState(
+        state.outer.v, state.outer.t + 1)), new_anchors, new_v, new_res)
 
 
 def _gossip_async_impl(cfg, replicate_fn, state, anchors, v, residual, pub,
@@ -1196,129 +1185,20 @@ def _gossip_async_impl(cfg, replicate_fn, state, anchors, v, residual, pub,
             pub_v_new)
 
 
-def _gossip_pair_live_impl(cfg, replicate_fn, state, anchors, v, residual,
-                           peer_idx, active, adopt, reset):
-    """``_gossip_pair_impl`` under quorum masks (all (K,) traced arrays —
-    fixed signature, a changing live set never retraces):
-
-    * ``active`` — contributors, pair-matched among themselves (a solo
-      leftover self-pairs: its pair mean is the identity, a solo outer
-      step);
-    * ``adopt``  — live veterans, whose post-round anchor mean is the
-      consensus estimate a rejoiner adopts;
-    * ``reset``  — rejoiners: anchors/params := consensus, outer momentum,
-      inner-opt and EF state := 0;
-    * rows in none of the masks (dead workers) pass through frozen.
-    """
-    rows = outer_opt._mask_rows
-    transport = outer_opt.make_transport(cfg, replicate_fn)
-    delta = jax.tree.map(
-        lambda w, a: w.astype(jnp.float32) - a.astype(jnp.float32),
-        state.worker_params, anchors)
-    dq, peer_dq, new_res = transport.exchange_peers(delta, peer_idx,
-                                                    residual)
-
-    def pair_mean(t):
-        peer_rows = jax.tree.map(lambda x: x[peer_idx], t)
-        return jax.tree.map(lambda a, b: a * 0.5 + b * 0.5, t, peer_rows)
-
-    base, v_mix = pair_mean(anchors), pair_mean(v)
-    avg = jax.tree.map(lambda a, b: a * 0.5 + b * 0.5, dq, peer_dq)
-    cand_anchors, cand_v = _gossip_outer_rows(cfg, state, base, v_mix, avg)
-
-    def merge(n, o):
-        return jnp.where(rows(active, n), n, o)
-
-    new_anchors = jax.tree.map(merge, cand_anchors, anchors)
-    new_v = jax.tree.map(merge, cand_v, v)
-    if new_res is not None:
-        new_res = jax.tree.map(merge, new_res, residual)
-    # rejoiners adopt the veterans' consensus with a clean slate
-    af = adopt.astype(jnp.float32)
-    n_adopt = jnp.maximum(jnp.sum(af), 1.0)
-    consensus = jax.tree.map(
-        lambda a: jnp.tensordot(af, a.astype(jnp.float32),
-                                axes=(0, 0)) / n_adopt, new_anchors)
-    new_anchors = jax.tree.map(
-        lambda a, c: jnp.where(rows(reset, a), c[None].astype(a.dtype), a),
-        new_anchors, consensus)
-    new_v = jax.tree.map(
-        lambda x: jnp.where(rows(reset, x), jnp.zeros_like(x), x), new_v)
-    if new_res is not None:
-        new_res = jax.tree.map(
-            lambda r: jnp.where(rows(reset, r), jnp.zeros_like(r), r),
-            new_res)
-    take = jnp.logical_or(active, reset)
-    new_wp = jax.tree.map(
-        lambda a, w: jnp.where(rows(take, w), a.astype(w.dtype), w),
-        new_anchors, state.worker_params)
-    new_opt = jax.tree.map(
-        lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-        state.inner_opt)
-    # global tracks the LIVE fleet's anchor mean; dead anchors are stale
-    lf = jnp.logical_or(adopt, reset).astype(jnp.float32)
-    n_live = jnp.maximum(jnp.sum(lf), 1.0)
-    new_global = jax.tree.map(
-        lambda a, g: (jnp.tensordot(lf, a.astype(jnp.float32),
-                                    axes=(0, 0)) / n_live).astype(g.dtype),
-        new_anchors, state.global_params)
-    new_state = state._replace(
-        global_params=new_global, worker_params=new_wp, inner_opt=new_opt,
-        outer=outer_opt.OuterState(state.outer.v, state.outer.t + 1))
-    return new_state, new_anchors, new_v, new_res
-
-
-def _gossip_adopt_impl(cfg, state, anchors, v, residual, reset, adopt):
-    """Rejoin on a skipped gossip round: ``reset`` rows adopt the ``adopt``
-    rows' CURRENT anchor consensus (no exchange, no outer update); the
-    veterans are untouched."""
-    del cfg     # uniform partial-binding signature with the pair impls
-    rows = outer_opt._mask_rows
-    af = adopt.astype(jnp.float32)
-    n = jnp.maximum(jnp.sum(af), 1.0)
-    consensus = jax.tree.map(
-        lambda a: jnp.tensordot(af, a.astype(jnp.float32), axes=(0, 0)) / n,
-        anchors)
-    new_anchors = jax.tree.map(
-        lambda a, c: jnp.where(rows(reset, a), c[None].astype(a.dtype), a),
-        anchors, consensus)
-    new_v = jax.tree.map(
-        lambda x: jnp.where(rows(reset, x), jnp.zeros_like(x), x), v)
-    if residual is not None:
-        residual = jax.tree.map(
-            lambda r: jnp.where(rows(reset, r), jnp.zeros_like(r), r),
-            residual)
-    new_wp = jax.tree.map(
-        lambda a, w: jnp.where(rows(reset, w), a.astype(w.dtype), w),
-        new_anchors, state.worker_params)
-    new_opt = jax.tree.map(
-        lambda o: jnp.where(rows(reset, o), jnp.zeros_like(o), o),
-        state.inner_opt)
-    lf = jnp.logical_or(adopt, reset).astype(jnp.float32)
-    n_live = jnp.maximum(jnp.sum(lf), 1.0)
-    new_global = jax.tree.map(
-        lambda a, g: (jnp.tensordot(lf, a.astype(jnp.float32),
-                                    axes=(0, 0)) / n_live).astype(g.dtype),
-        new_anchors, state.global_params)
-    return state._replace(global_params=new_global, worker_params=new_wp,
-                          inner_opt=new_opt), new_anchors, new_v, residual
-
-
 def _jit_gossip_pair(engine, donate: bool):
     fn = functools.partial(_gossip_pair_impl, engine.cfg,
                            engine.replicate_fn)
     return jax.jit(fn, donate_argnums=(0, 1, 2, 3) if donate else ())
 
 
-def _jit_gossip_pair_live(engine, donate: bool):
-    fn = functools.partial(_gossip_pair_live_impl, engine.cfg,
-                           engine.replicate_fn)
-    return jax.jit(fn, donate_argnums=(0, 1, 2, 3) if donate else ())
+def _jit_gossip_adopt(donate: bool):
+    """Rejoin on a skipped gossip round: ``reset`` rows adopt the ``adopt``
+    rows' CURRENT anchor consensus (no exchange, no outer update); the
+    veterans are untouched."""
+    def adopt(state, anchors, v, residual, reset, adopt):
+        return _gossip_land(state, anchors, v, residual, reset, adopt, reset)
 
-
-def _jit_gossip_adopt(engine, donate: bool):
-    fn = functools.partial(_gossip_adopt_impl, engine.cfg)
-    return jax.jit(fn, donate_argnums=(0, 1, 2, 3) if donate else ())
+    return jax.jit(adopt, donate_argnums=(0, 1, 2, 3) if donate else ())
 
 
 class _GossipRunner(SyncRunner):
@@ -1356,58 +1236,39 @@ class _GossipRunner(SyncRunner):
             lambda p: jnp.zeros((self.k,) + p.shape, jnp.float32), params)
         self.residual = engine.init_residual(params)
         self._donate = donate
-        self._tracker = None
         self._sync = _jit_gossip_pair(engine, donate)
 
     def bind_faults(self, tracker):
         self._tracker = tracker
-        self._syncq = _jit_gossip_pair_live(self.engine, self._donate)
-        self._adoptg = _jit_gossip_adopt(self.engine, self._donate)
+        self._adoptg = _jit_gossip_adopt(self._donate)
 
     def _do_sync(self, state, step):
-        if self._tracker is None:
-            peers = gossip_peers(self.k, self.round, self.topology,
-                                 self.seed)
-            records = [("gossip_syncs", (step, w, peers[w], 0))
-                       for w in range(self.k)]
-            records.append(("sync_steps", step))
-            state, self.anchors, self.outer_v, self.residual = self._sync(
-                state, self.anchors, self.outer_v, self.residual,
-                jnp.asarray(peers, jnp.int32))
-            self.round += 1
-            return state, records
-        info = self._tracker.round_masks(step)
-        records = list(info.records)
-        if any(info.reset):
-            records += _rejoin_drift_records(state, info.reset, info.live,
-                                             step)
-        reset = jnp.asarray(info.reset)
-        adopt = jnp.asarray(info.adopt)
-        if info.skip:
+        info, records = self._round_masks(state, step)
+        if info is not None and info.skip:
             if any(info.reset):
                 (state, self.anchors, self.outer_v,
                  self.residual) = self._adoptg(
                     state, self.anchors, self.outer_v, self.residual,
-                    reset, adopt)
+                    jnp.asarray(info.reset), jnp.asarray(info.adopt))
             self.round += 1
             return state, records
-        # deterministic matching over the surviving contributors only:
-        # the sub-fleet's schedule is mapped back through the sorted
+        # deterministic matching over the contributors only: the
+        # sub-fleet's schedule is mapped back through the sorted
         # contributor indices, so any two boxes replaying the same
         # schedule pair the same workers
-        contributors = [w for w in range(self.k) if info.contrib[w]]
+        contributors = [w for w in range(self.k)
+                        if info is None or info.contrib[w]]
         sub = gossip_peers(len(contributors), self.round, self.topology,
                            self.seed)
         peers = list(range(self.k))
         for i, w in enumerate(contributors):
             peers[w] = contributors[sub[i]]
-        for w in contributors:
-            records.append(("gossip_syncs", (step, w, peers[w], 0)))
+        records += [("gossip_syncs", (step, w, peers[w], 0))
+                    for w in contributors]
         records.append(("sync_steps", step))
-        state, self.anchors, self.outer_v, self.residual = self._syncq(
+        state, self.anchors, self.outer_v, self.residual = self._sync(
             state, self.anchors, self.outer_v, self.residual,
-            jnp.asarray(peers, jnp.int32), jnp.asarray(info.contrib),
-            adopt, reset)
+            jnp.asarray(peers, jnp.int32), *_masks(info))
         self.round += 1
         return state, records
 

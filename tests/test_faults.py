@@ -13,6 +13,7 @@ The anchors, in order of strictness:
 * kill -> checkpoint -> --resume continues BITWISE vs an uninterrupted
   run (state, loss history, sync steps), for DDP and DiLoCo.
 """
+import functools
 import os
 
 import jax
@@ -164,9 +165,9 @@ def test_quorum_one_dead_matches_survivor_fleet_bitwise():
     eng3 = DiLoCoTrainer(None, OPT, DiLoCoConfig(num_workers=3))
     st4, st3 = with_rows(eng4, rows), with_rows(eng3, rows[:3])
     contrib = jnp.array([1, 1, 1, 0], bool)
-    new4, _ = jax.jit(eng4.outer_step_quorum)(
+    new4, _ = jax.jit(eng4.outer_step_ef)(
         st4, None, contrib, contrib, jnp.zeros(4, bool))
-    new3q, _ = jax.jit(eng3.outer_step_quorum)(
+    new3q, _ = jax.jit(eng3.outer_step_ef)(
         st3, None, jnp.ones(3, bool), jnp.ones(3, bool), jnp.zeros(3, bool))
     _assert_tree_equal(new4.global_params, new3q.global_params)
     # live rows adopt the (identical) new anchor...
@@ -183,6 +184,92 @@ def test_quorum_one_dead_matches_survivor_fleet_bitwise():
                     jax.tree.leaves(new3.global_params)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    atol=1e-6, rtol=0)
+
+
+def _selects(jitted, *args) -> int:
+    return jitted.lower(*args).compile().as_text().count(" select(")
+
+
+def _fleet_program(program, k):
+    """``(fn, args, n_masks, reference_selects)`` for one program at
+    K=``k`` on the tiny dense config: the unjitted program, its arguments
+    before the fleet masks, how many (K,) masks it takes, and the
+    ``select`` count of the same program written with no fleet masks."""
+    from repro.core.streaming import fragment_masks
+    cfg, m, params, dcfg = _setup(k=k, h=2, strategy="pipelined",
+                                  num_fragments=2)
+    dt = DistTrainer(m.loss, OPT, dcfg, make_strategy(dcfg))
+    eng, st = dt.engine(), dt.init(params)
+    # divergent workers with live optimizer moments, so that every mask
+    # (which rows average, adopt, reset) shows in the result
+    st = st._replace(
+        worker_params=jax.tree.map(
+            lambda *r: jnp.stack(r),
+            *[_noise_row(params, 200 + i) for i in range(k)]),
+        inner_opt=jax.tree.map(lambda x: x + 0.5, st.inner_opt))
+    frag = fragment_masks(params, 2)[1]
+    if program == "outer_step":
+        return eng.outer_step_ef, (st, None), 3, 0
+    if program == "fragment_step":
+        # the global and worker merges of every leaf: the fragment mask
+        # is an argument here
+        return (eng.outer_step_fragment_ef, (st, frag, None), 3,
+                2 * len(jax.tree.leaves(frag)))
+    if program == "pipelined_apply":
+        runner = dt.strategy.bind(eng, params, donate=False)
+        snap = jax.tree.map(lambda w: w * 0.5, st.worker_params)
+        # the runner's fragment masks are constants, so only the leaves
+        # that the fragment splits keep their two merges
+        mixed = sum(1 for x in jax.tree.leaves(frag) if 0 < x.sum() < x.size)
+        return (functools.partial(runner._apply_impl, frag=1),
+                (st, snap, None), 3, 2 * mixed)
+    batches = {key: jnp.stack([v, v[::-1]])
+               for key, v in _data(cfg, k, 0).items()}
+
+    def plain_chunk(state, bs):
+        def body(s, b):
+            s, loss, _ = eng.inner_step(s, b)
+            return s, loss
+        return jax.lax.scan(body, state, bs)
+
+    return (eng.inner_chunk, (st, batches), 1,
+            _selects(jax.jit(plain_chunk), st, batches))
+
+
+def _all_live(k, n_masks):
+    """(live/contrib, adopt, reset)[:n_masks] for a fleet with no fault."""
+    return (jnp.ones(k, bool), jnp.ones(k, bool), jnp.zeros(k, bool))[:n_masks]
+
+
+@pytest.mark.parametrize("program", ["outer_step", "fragment_step",
+                                     "pipelined_apply", "inner_chunk"])
+def test_whole_fleet_default_masks_fold_to_the_unmasked_program(program):
+    """One program per strategy serves faulty and fault-free runs.
+
+    * Left out, the fleet masks mean the whole fleet: the default call is
+      bitwise the call with all-live masks built inside the trace.
+    * XLA folds the default masks away: the compiled program has as many
+      ``select`` ops as one written with no fleet masks at all (none for
+      the DiLoCo outer step, at K=1 and K=4).
+    * All-live masks passed as traced arrays compute the same state.  That
+      program keeps its selects, and XLA:CPU contracts mul+add chains into
+      FMAs per compiled module, so it agrees to float32 rounding, not
+      bitwise: a few ulps of values below 1 after at most two optimizer
+      steps.
+    """
+    fn, args, n_masks, reference = _fleet_program(program, 4)
+    default = jax.jit(fn)
+    out = default(*args)
+    _assert_tree_equal(
+        out, jax.jit(lambda *a: fn(*a, *_all_live(4, n_masks)))(*args))
+    assert _selects(default, *args) == reference
+    traced = default(*args, *_all_live(4, n_masks))
+    for x, y in zip(jax.tree.leaves(out), jax.tree.leaves(traced)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=0, atol=1e-6)
+    if program == "outer_step":
+        fn, args, _, reference = _fleet_program(program, 1)
+        assert _selects(jax.jit(fn), *args) == reference
 
 
 # ---------------------------------------------------------------------------
@@ -232,9 +319,10 @@ def test_drop_retry_keeps_worker_in_and_matches_no_fault_run():
     base_state, base_hist = _run_with(dcfg, cfg, m, params, 8, 2)
     # one failed attempt -> codec-aware retry succeeds, worker stays in:
     # full quorum, same math as the no-fault round (allclose-tight — the
-    # quorum jit is a different compiled program, so XLA's fusion choices
-    # may differ by an ulp; BITWISE no-fault equality is pinned on the
-    # empty-schedule path, which keeps the original programs)
+    # outer step with traced masks is a different compiled program from
+    # its whole-fleet default, so XLA's fusion choices may differ by an
+    # ulp; BITWISE no-fault equality is pinned on the empty-schedule path,
+    # which passes no masks)
     faults = FaultSchedule.from_spec("drop:1@3")
     state, hist = _run_with(dcfg, cfg, m, params, 8, 2, faults=faults)
     assert (3, "drop_retry", 1) in hist["fault"]
