@@ -7,6 +7,9 @@ import pytest
 from repro.kernels.flash_attention import (flash_attention,
                                            reference_attention,
                                            reference_attention_fp8)
+from repro.kernels.newton_schulz import (newton_schulz_pallas,
+                                         reference_newton_schulz)
+from repro.kernels.newton_schulz.kernel import Plan, newton_schulz_fwd
 from repro.kernels.rmsnorm import (reference_rmsnorm,
                                    reference_rmsnorm_residual, rmsnorm,
                                    rmsnorm_residual)
@@ -494,3 +497,54 @@ def test_paged_decode_attention_ignores_unmapped_and_stale():
     vp2 = vp2.at[0, 5:].set(-1e4)
     out2 = paged_decode_attention(q, kp2, vp2, tables, q_pos)
     assert float(jnp.max(jnp.abs(out1 - out2))) == 0.0
+
+
+# Newton-Schulz: every matmul rounds its operands to bfloat16, so a float32
+# summation-order difference (tiles vs one contraction) can flip a
+# bfloat16 rounding at a few entries.  The relative Frobenius gap to the
+# oracle after one iteration stays below 1.3e-6 (56 seeds and shapes);
+# the quintic's gain carries it to 1.6e-2 by the fifth, where a one-ulp
+# change of G moves the oracle itself as far.  A rounding point out of
+# place (b·A on the bfloat16 A) reads 2e-5 after one iteration.
+_NS_TOL = {0: 5e-6, 1: 5e-6, 5: 5e-2}
+
+
+def _ns_gap(out, ref):
+    return float(jnp.linalg.norm(out - ref) / jnp.linalg.norm(ref))
+
+
+@pytest.mark.parametrize("shape", [(2, 128, 384), (2, 384, 128),
+                                   (1, 256, 256), (1, 256, 512),
+                                   (1, 512, 256)])
+@pytest.mark.parametrize("steps", [0, 1, 5])
+def test_newton_schulz_kernel_resident_and_streamed(shape, steps):
+    """Both plans of the kernel, wide, tall and square, with 128-wide tiles
+    (several per sweep): the resident and the streamed iterate take the
+    same tiles in the same order, so they agree bit for bit, and both match
+    the oracle's bfloat16-operand rounding."""
+    G = jax.random.normal(jax.random.key(sum(shape) + steps), shape)
+    run = lambda resident: newton_schulz_fwd(
+        G, steps=steps, eps=1e-7, plan=Plan(128, resident, 0),
+        interpret=True)
+    res, stream = run(True), run(False)
+    np.testing.assert_array_equal(np.asarray(res), np.asarray(stream))
+    ref = reference_newton_schulz(G, steps)
+    gap = _ns_gap(res, ref)
+    assert gap < _NS_TOL[steps], gap
+
+
+@pytest.mark.parametrize("shape", [(128, 256), (3, 256, 128),
+                                   (2, 2, 128, 128)])
+@pytest.mark.parametrize("steps", [1, 5])
+def test_newton_schulz_pallas_leading_dims_and_vmap(shape, steps):
+    """The public wrapper on no, one and two leading dims, and under a
+    vmapped worker dim K=2, as the inner chunk calls it."""
+    G = jax.random.normal(jax.random.key(len(shape) * 10 + steps),
+                          (2,) + shape)
+    out = jax.vmap(lambda g: newton_schulz_pallas(g, steps))(G)
+    assert out.shape == G.shape and out.dtype == jnp.float32
+    ref = reference_newton_schulz(G, steps)
+    gap = _ns_gap(out, ref)
+    assert gap < _NS_TOL[steps], gap
+    one = newton_schulz_pallas(G[1], steps)
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(one))

@@ -1,5 +1,7 @@
 """Optimizers: AdamW reference math, Muon orthogonalization, partitioning,
 schedules."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -84,6 +86,63 @@ def test_newton_schulz_is_straight_line_matmuls(shape):
         if eqn.primitive.name == "dot_general":
             assert eqn.outvars[0].aval.shape[-2:] in {(small, small),
                                                      shape[-2:]}
+
+
+# the Muon leaves of the two training cells: nanochat-d20, Qwen1.5-0.5B
+CELL_LEAVES = [(20, 1280, 1280), (20, 1280, 5120), (20, 5120, 1280),
+               (24, 1024, 1024), (24, 1024, 2816), (24, 2816, 1024)]
+
+
+@pytest.mark.parametrize("shape", CELL_LEAVES)
+def test_select_ns_impl_takes_the_kernel_on_a_tpu(shape):
+    from repro.optim.muon import select_ns_impl
+    assert select_ns_impl(backend="tpu", dtype=jnp.float32,
+                          shape=shape) == "pallas"
+    assert select_ns_impl(backend="cpu", dtype=jnp.float32,
+                          shape=shape) == "xla"
+
+
+@pytest.mark.parametrize("shape,dtype,why", [
+    ((24, 1000, 1000), jnp.float32, "sides not multiples of 128"),
+    ((4, 128, 200), jnp.float32, "one side not a multiple of 128"),
+    ((4096, 4096), jnp.float32, "Gram side beyond the VMEM budget"),
+    ((8, 4096, 11008), jnp.float32, "Gram side beyond the VMEM budget"),
+    ((20, 1280, 1280), jnp.bfloat16, "not float32"),
+    ((0, 128, 128), jnp.float32, "empty stack"),
+    ((128,), jnp.float32, "not a matrix"),
+])
+def test_select_ns_impl_keeps_xla(shape, dtype, why):
+    from repro.optim.muon import select_ns_impl
+    assert select_ns_impl(backend="tpu", dtype=dtype, shape=shape) == \
+        "xla", why
+
+
+def _traced_ns_record(arch):
+    from repro.models.transformer import abstract_params
+    from repro.configs.registry import get_config
+    muon_mod = importlib.import_module("repro.optim.muon")
+    params, _ = abstract_params(get_config(arch))
+    opt = nanochat_optimizer(OptimizerConfig())
+    st = jax.eval_shape(opt.init, params)
+    jax.eval_shape(lambda g, s, p: opt.update(g, s, p, 0), params, st,
+                   params)
+    return dict(muon_mod.NS_TRACE)
+
+
+@pytest.mark.parametrize("arch", ["nanochat-d20", "qwen1.5-0.5b"])
+def test_ns_trace_names_the_kernel_for_every_cell_leaf(arch, monkeypatch):
+    """Traced as on a TPU, every Muon leaf of both cells takes the kernel
+    and so do all of its Newton-Schulz FLOPs; traced on a CPU, none."""
+    muon_mod = importlib.import_module("repro.optim.muon")
+    cpu = _traced_ns_record(arch)
+    assert set(cpu["paths"].values()) == {"xla"}
+    assert cpu["kernel_flop_share"] == 0.0
+    monkeypatch.setattr(muon_mod, "_backend", lambda: "tpu")
+    tpu = _traced_ns_record(arch)
+    assert tpu["paths"].keys() == cpu["paths"].keys()
+    assert len(tpu["paths"]) == (6 if arch == "nanochat-d20" else 7)
+    assert set(tpu["paths"].values()) == {"pallas"}
+    assert tpu["kernel_flop_share"] == 1.0
 
 
 def test_sgd_nesterov_math():

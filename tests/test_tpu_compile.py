@@ -1,7 +1,7 @@
 """Compile rehearsal of the main-path Pallas kernels for one described TPU
 v5e chip at nanochat-d20 widths (head_dim 128, 10 KV heads, d_ff 5120,
-vocab 65536), and of the training path's flash attention at d20's and
-Qwen1.5-0.5B's head widths.  Nothing runs: Mosaic and XLA:TPU compile for a chip that is
+vocab 65536), and of the training path's flash attention and
+Newton-Schulz kernels at d20's and Qwen1.5-0.5B's widths.  Nothing runs: Mosaic and XLA:TPU compile for a chip that is
 described, not attached, and refuse what the chip would refuse — tiling
 violations and VMEM overruns that interpret-mode tests cannot see.
 
@@ -116,3 +116,21 @@ def test_flash_attention_grad_compiles(one_chip, H, D):
     compiled = _compile(grads, one_chip, *[((1, H, S, D), jnp.float32)] * 4)
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         == 3
+
+
+@pytest.mark.parametrize("shape", [
+    (20, 1280, 1280), (20, 1280, 5120), (20, 5120, 1280),     # d20
+    (24, 1024, 1024), (24, 1024, 2816), (24, 2816, 1024)])    # Qwen
+def test_newton_schulz_compiles_at_cell_leaves(one_chip, shape):
+    """Each Muon leaf of both training cells, under vmap over K=1 workers
+    inside a scan, as the inner chunk runs it: the kernel's plan (resident
+    or streamed iterate) fits the VMEM it asks for."""
+    from repro.kernels.newton_schulz import newton_schulz_pallas
+
+    def chunk(g):
+        ns = jax.vmap(lambda x: newton_schulz_pallas(x, 5, interpret=False))
+        return jax.lax.scan(lambda c, _: (ns(c), None), g, None, length=2)[0]
+
+    compiled = _compile(chunk, one_chip, ((1,) + shape, jnp.float32))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
